@@ -368,6 +368,8 @@ def main(argv=None) -> None:
         ap.error(f"--only {args.only!r} matches no section "
                  f"(have: {[n for n, _ in SECTIONS]})")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     _ROWS.clear()               # fresh trajectory per in-process run
     print("name,us_per_call,derived")
     results: Dict[str, Any] = {}

@@ -7,7 +7,7 @@ use, reuse them while warm, and persist results to object storage — the
 full Hardless §IV lifecycle with actual model execution on this host.
 
 Backend exercised: sim (pod cluster on the virtual clock) with REAL
-reduced-config JAX forwards inside each simulated node.
+CPU-sized (``<arch>-smoke``) JAX forwards inside each simulated node.
 
     PYTHONPATH=src python examples/serve_cluster.py
 """
@@ -34,8 +34,8 @@ profiles = {
     "v5e-2x2": SimProfile(elat_median_s=0.6, cold_start_s=2.0),
 }
 runtimes = {}
-for arch in ("granite-3-2b", "qwen2.5-14b"):
-    rdef = make_serve_runtime(get_config(arch).reduced(),
+for arch in ("granite-3-2b-smoke", "qwen2.5-14b-smoke"):
+    rdef = make_serve_runtime(get_config(arch),
                               acc_types=profiles, max_slots=4, max_len=64)
     cluster.register_runtime(rdef)
     runtimes[arch] = rdef

@@ -3,7 +3,8 @@ against the calibrated cluster simulation and against real JAX execution
 on this host — only the backend handed to the Gateway changes.
 
 Backends exercised: BOTH — sim (roofline service times, virtual clock)
-then engine (real reduced-config execution on this host's JAX devices).
+then engine (real execution of the CPU-sized ``-smoke`` config on this
+host's JAX devices).
 
     PYTHONPATH=src python examples/unified_gateway.py
 """
@@ -54,9 +55,9 @@ sim_gw.register(RuntimeDef(
     profiles={"v5e-4x4": roofline_profile(cfg_full, batch=1, new_tokens=4)}))
 run_client(sim_gw, f"serve-{cfg_full.name}")
 
-# -- backend 2: real JAX engine on this host (reduced config) ------------
+# -- backend 2: real JAX engine on this host (CPU-sized config) ----------
 print("engine backend (real execution: cold = jit + weights, warm = reuse):")
-cfg_red = get_config(ARCH).reduced()
+cfg_red = get_config(f"{ARCH}-smoke")
 eng_gw = Gateway(EngineBackend())
 eng_gw.register(make_serve_runtime(cfg_red, max_slots=2, max_len=48))
 run_client(eng_gw, f"serve-{cfg_red.name}")

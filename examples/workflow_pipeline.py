@@ -52,8 +52,8 @@ def vision_runtime() -> RuntimeDef:
 
 
 def audio_runtime() -> RuntimeDef:
-    """Whisper-tiny transcription (reduced config, stub mel frontend)."""
-    cfg = get_config("whisper-tiny").reduced()
+    """Whisper-tiny transcription (CPU-sized config, stub mel frontend)."""
+    cfg = get_config("whisper-tiny-smoke")
 
     def setup():
         return M.init_model_params(cfg, jax.random.PRNGKey(1))
@@ -80,7 +80,7 @@ def audio_runtime() -> RuntimeDef:
 def caption_runtime() -> RuntimeDef:
     """LLM captioner: fuses the gathered vision+audio outputs to a prompt
     and generates through a warm ServingEngine (jit + weights on cold)."""
-    cfg = get_config("granite-3-2b").reduced()
+    cfg = get_config("granite-3-2b-smoke")
 
     def setup():
         params = M.init_model_params(cfg, jax.random.PRNGKey(2))

@@ -26,6 +26,7 @@ the benches, and the process-death tests all use.
 """
 from __future__ import annotations
 
+import glob
 import hashlib
 import json
 import os
@@ -408,16 +409,50 @@ class ClusterCapacityHooks(CapacityHooks):
         self.backend.transport.pin(sorted(keys))
 
 
+def host_tpu_chips() -> int:
+    """TPU chips attached to this host, counted from their device files
+    (``/dev/accel*``, or ``/dev/vfio/<n>`` where the chips are passed
+    through VFIO, as on v5e) without initialising a JAX backend; 0 where
+    JAX is held off the TPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+TPU_PORT_BASE = 8476
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """libtpu's per-process variables that give a process chip ``chip``
+    alone, as an independent one-chip slice with its own port.  libtpu
+    takes one lock per host; the launcher hands out distinct chips, so
+    each worker is let past it."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(TPU_PORT_BASE + chip),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{TPU_PORT_BASE + chip}",
+            "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+
+
 class WorkerLauncher:
     """Spawn/kill/scale worker subprocesses against one master address.
 
     ``kill()`` is SIGKILL — the real-process-death fault path the
     ``kill-worker-process`` fault op and the SIGKILL tests drive;
-    ``stop_all()`` is the polite SIGTERM-then-SIGKILL shutdown."""
+    ``stop_all()`` is the polite SIGTERM-then-SIGKILL shutdown.
+
+    On a TPU host a chip belongs to one process.  With ``pin_chips``
+    (workers that serve JAX runtimes) worker processes each get one chip
+    of their own, and spawning more live workers than the host has chips
+    is refused; without it, workers are held off the TPU altogether."""
 
     def __init__(self, addr: str, *, max_batch: int = 8,
                  heartbeat_s: float = 0.5, max_warm: int = 8,
-                 acc_types: Optional[Sequence[str]] = None):
+                 acc_types: Optional[Sequence[str]] = None,
+                 pin_chips: bool = False):
         self.addr = addr
         self.max_batch = max_batch
         self.heartbeat_s = heartbeat_s
@@ -426,23 +461,47 @@ class WorkerLauncher:
         # around when more workers spawn than types were given); None
         # leaves the worker's host-jax default
         self.acc_types = list(acc_types) if acc_types else None
+        self.pin_chips = pin_chips
         self._procs: List[Optional[subprocess.Popen]] = []
+        self._chips: Dict[int, int] = {}     # worker idx -> its chip
 
-    def _env(self) -> Dict[str, str]:
-        """The child env: this repro package's ``src`` on PYTHONPATH."""
+    def _env(self, chip: Optional[int] = None) -> Dict[str, str]:
+        """The child env: this repro package's ``src`` on PYTHONPATH, plus
+        chip ``chip`` alone, or no TPU at all on a chip host."""
         import repro
         # repro is a namespace package: __file__ is None, __path__ works
         src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
         env = dict(os.environ)
         env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
                                    if env.get("PYTHONPATH") else "")
+        if chip is not None:
+            env.update(chip_env(chip))
+        elif host_tpu_chips():
+            env["JAX_PLATFORMS"] = "cpu"
         return env
+
+    def _free_chips(self, n: int) -> List[Optional[int]]:
+        """Chips for ``n`` new workers: none on a host without TPU chips;
+        otherwise the lowest chips no live worker holds, or refusal."""
+        chips = host_tpu_chips() if self.pin_chips else 0
+        if not chips:
+            return [None] * n
+        held = {self._chips[i] for i in self.alive() if i in self._chips}
+        free = [c for c in range(chips) if c not in held]
+        if n > len(free):
+            raise RuntimeError(
+                f"refusing to start {n} more JAX-serving worker(s): this "
+                f"host has {chips} TPU chip(s) and {len(held)} already "
+                f"belong to live workers (one worker process per chip)")
+        return free[:n]
 
     def spawn(self, n: int = 1) -> List[str]:
         """Start ``n`` worker processes; returns their names (``w<i>``)."""
         names = []
-        for _ in range(n):
+        for chip in self._free_chips(n):
             idx = len(self._procs)
+            if chip is not None:
+                self._chips[idx] = chip
             name = f"w{idx}"
             # -c instead of -m: runpy warns when the package __init__ has
             # already imported the worker module it is about to re-execute
@@ -457,7 +516,7 @@ class WorkerLauncher:
                 cmd += ["--acc-type",
                         self.acc_types[idx % len(self.acc_types)]]
             self._procs.append(subprocess.Popen(
-                cmd, env=self._env(), stdout=subprocess.DEVNULL))
+                cmd, env=self._env(chip), stdout=subprocess.DEVNULL))
             names.append(name)
         return names
 
@@ -486,8 +545,18 @@ class WorkerLauncher:
             for idx in live[n:]:
                 self._procs[idx].terminate()
 
-    def stop_all(self) -> None:
-        """SIGTERM everyone, SIGKILL stragglers, reap them all."""
+    def stop_all(self, grace_s: float = 0.0) -> None:
+        """Give workers ``grace_s`` to exit on their own (they leave their
+        take loop once the master flags shutdown, and a TPU worker then
+        releases its chip cleanly), SIGTERM the rest, SIGKILL stragglers,
+        reap them all."""
+        deadline = time.monotonic() + grace_s
+        for p in self._procs:
+            if p is not None and p.poll() is None:
+                try:
+                    p.wait(timeout=max(deadline - time.monotonic(), 0.0))
+                except subprocess.TimeoutExpired:
+                    pass
         for p in self._procs:
             if p is not None and p.poll() is None:
                 p.terminate()
@@ -530,7 +599,7 @@ class ClusterHandle:
         their take loops), the launcher reaps the processes, the backend
         stops its pump, the master's server stops."""
         self.master.op_shutdown()
-        self.launcher.stop_all()
+        self.launcher.stop_all(grace_s=10.0)
         self.backend.shutdown()
         self.master.stop()
 
@@ -541,22 +610,31 @@ def start_cluster(n_workers: int, *, lease_s: float = 30.0,
                   heartbeat_s: float = 0.5, max_batch: int = 8,
                   max_warm: int = 8,
                   acc_types: Optional[Sequence[str]] = None,
-                  ready_timeout_s: float = 20.0) -> ClusterHandle:
+                  ready_timeout_s: float = 20.0,
+                  pin_chips: bool = False) -> ClusterHandle:
     """Bring up master + ``n_workers`` worker processes on loopback.
 
     Blocks until every worker has said hello (readiness), so callers can
     submit immediately.  ``heartbeat_timeout_s`` decides how fast a
     SIGKILLed worker is declared dead and its leases requeued — keep it
     comfortably above the slowest ``setup()`` a runtime performs (a jit
-    compile must not read as death; serve workloads want ~30 s)."""
+    compile must not read as death; serve workloads want ~30 s).
+    ``pin_chips``: the workers serve JAX runtimes — one chip each on a
+    TPU host (see :class:`WorkerLauncher`); this process never touches
+    JAX either way."""
     master = Master(lease_s=lease_s,
                     heartbeat_timeout_s=heartbeat_timeout_s,
                     keeper_interval_s=keeper_interval_s)
     addr = master.serve()
     launcher = WorkerLauncher(addr, max_batch=max_batch,
                               heartbeat_s=heartbeat_s, max_warm=max_warm,
-                              acc_types=acc_types)
-    launcher.spawn(n_workers)
+                              acc_types=acc_types, pin_chips=pin_chips)
+    try:
+        launcher.spawn(n_workers)
+    except Exception:
+        launcher.stop_all()
+        master.stop()
+        raise
     backend = ClusterBackend(RpcTransport(addr), launcher=launcher)
     deadline = time.monotonic() + ready_timeout_s
     while time.monotonic() < deadline:

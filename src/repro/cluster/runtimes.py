@@ -20,9 +20,10 @@ The factories below are module-level (importable from a bare
 * :func:`add_runtime` — instant arithmetic echo for workflow-chain
   tests (child input = parent output + ``add``).
 * :func:`serve_runtime` — the real thing: wraps
-  :func:`repro.serve.api.make_serve_runtime` over a reduced model
-  config, so ``launch/serve.py --cluster N`` generates with actual JAX
-  execution inside each worker process.
+  :func:`repro.serve.api.make_serve_runtime` over the config ``arch``
+  names (``<arch>-smoke`` for the CPU-sized variant), so
+  ``launch/serve.py --cluster N`` generates with actual JAX execution
+  inside each worker process.
 """
 from __future__ import annotations
 
@@ -91,15 +92,17 @@ def add_runtime(runtime_id: str = "add", add: int = 1,
 def serve_runtime(arch: str = "granite-3-2b", max_batch: int = 4,
                   max_slots: int = 4, max_len: int = 64,
                   page_size: int = 16,
-                  prefill_chunk: int = 0) -> RuntimeDef:
-    """A real generation runtime over a reduced config (jit + sampling
-    inside the worker process; heavy imports deferred to load time).
+                  prefill_chunk: int = 0, seed: int = 0) -> RuntimeDef:
+    """A real generation runtime over ``get_config(arch)`` (jit +
+    sampling inside the worker process; heavy imports deferred to load
+    time).
     ``page_size``/``prefill_chunk`` select the worker engines' KV cache
     layout (0 = the dense per-slot reference) — they travel in the spec
-    kwargs, so every worker process serves off the same layout."""
+    kwargs, so every worker process serves off the same layout and the
+    same weights (drawn from ``seed``)."""
     from repro.configs import get_config
     from repro.serve.api import make_serve_runtime
-    cfg = get_config(arch).reduced()
+    cfg = get_config(arch)
     return make_serve_runtime(cfg, max_slots=max_slots, max_len=max_len,
                               max_batch=max_batch, page_size=page_size,
-                              prefill_chunk=prefill_chunk)
+                              prefill_chunk=prefill_chunk, seed=seed)

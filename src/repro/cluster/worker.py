@@ -30,6 +30,7 @@ from __future__ import annotations
 import argparse
 import os
 import pickle
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -41,9 +42,24 @@ from repro.core.storage import make_outcome, unwrap_outcome
 from repro.cluster.rpc import (RpcClient, decode_blob, encode_blob,
                                inv_from_wire)
 from repro.cluster.runtimes import load_runtime_spec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import TRACER
 
 DATA_CACHE_MAX = 64
+
+
+def device_report() -> Optional[Dict[str, Any]]:
+    """The JAX devices this process serves on, as JAX reports them; None
+    while no runtime has loaded JAX (sleep/add runtimes never do)."""
+    if "jax" not in sys.modules:
+        return None
+    import jax
+    devs = jax.devices()
+    # a one-chip process sees its chip at coords (0,0,0): the chip's
+    # identity on the host is the visible-chip index it was given
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS")}
 
 
 class Worker:
@@ -83,6 +99,7 @@ class Worker:
         self.n_settle_refused = 0
         self.n_data_local = 0       # input reads served from the cache
         self._inflight_n = 0        # events mid-execution (heartbeat stat)
+        self._device: Optional[Dict[str, Any]] = None   # after a setup()
 
     def now(self) -> float:
         """Current time on the master clock."""
@@ -180,6 +197,8 @@ class Worker:
         except Exception as e:  # noqa: BLE001 — settles as unsuccessful
             return None, True, False, f"cold-start failed: {e!r}"
         with self._lock:
+            if self._device is None:
+                self._device = device_report()
             self._handles[key] = handle
             self._evict_over_budget_locked()
         return handle, True, False, None
@@ -313,7 +332,8 @@ class Worker:
                 "busy": self._inflight_n,
                 "n_warm": len(warm_keys),
                 "n_data_local": self.n_data_local,
-                "warm_keys": warm_keys}
+                "warm_keys": warm_keys,
+                "device": self._device}
 
     def _heartbeat_loop(self) -> None:
         while True:
@@ -376,6 +396,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="accelerator type this worker reports "
                          "(heterogeneity view in stats/metrics)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     worker = Worker(args.master, args.name, max_batch=args.max_batch,
                     heartbeat_s=args.heartbeat_s, max_warm=args.max_warm,
                     acc_type=args.acc_type)

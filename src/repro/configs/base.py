@@ -150,7 +150,7 @@ class ModelConfig:
         n_layers = min(len(self.pattern), 3)
         return dataclasses.replace(
             self,
-            name=self.name + "-smoke",
+            name=self.name + SMOKE_SUFFIX,
             n_layers=max(2, n_layers) if len(self.pattern) == 1 else n_layers,
             d_model=d,
             n_heads=n_heads,
@@ -201,13 +201,23 @@ def register(arch_id: str):
     return deco
 
 
+SMOKE_SUFFIX = "-smoke"
+
+
 def get_config(arch_id: str) -> ModelConfig:
+    """The registered config, at its published widths; ``<arch>-smoke``
+    names ``get_config(<arch>).reduced()`` (the CPU-sized variant)."""
     if arch_id not in _REGISTRY:
         # import side-effect registration
         from repro import configs  # noqa: F401
         configs.load_all()
+    base = arch_id[:-len(SMOKE_SUFFIX)] \
+        if arch_id.endswith(SMOKE_SUFFIX) else None
+    if base in _REGISTRY:
+        return _REGISTRY[base]().reduced()
     if arch_id not in _REGISTRY:
-        raise KeyError(f"unknown arch '{arch_id}'; known: {sorted(_REGISTRY)}")
+        raise KeyError(f"unknown arch '{arch_id}'; known: {sorted(_REGISTRY)}"
+                       f" (each also as '<arch>{SMOKE_SUFFIX}')")
     return _REGISTRY[arch_id]()
 
 

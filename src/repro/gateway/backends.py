@@ -477,6 +477,9 @@ class EngineBackend(Backend):
         self.n_batches = 0
         self.batch_sizes: List[int] = []
         self._handles: "OrderedDict[str, Any]" = OrderedDict()
+        # runtime_key -> index of the device its warm instance lives on
+        # (it always executes there, whichever worker runs the batch)
+        self._handle_dev: Dict[str, int] = {}
         self._handle_idle_since: Dict[str, float] = {}
         self._pinned: Set[str] = set()       # min-warm keys, never evicted
         self._prewarmed: Set[str] = set()    # installed by prewarm, unserved
@@ -510,11 +513,7 @@ class EngineBackend(Backend):
         if self._started or self._shutdown:
             return
         self._started = True
-        try:
-            import jax
-            self._devices = list(jax.devices())
-        except Exception:
-            self._devices = []
+        self._resolve_devices_locked()
         if self._target_workers is None:
             n = self._n_workers_req
             if n is None:
@@ -889,13 +888,15 @@ class EngineBackend(Backend):
 
     def _drop_handle_locked(self, key: str) -> None:
         self._handles.pop(key, None)
+        self._handle_dev.pop(key, None)
         self._handle_idle_since.pop(key, None)
         self._prewarmed.discard(key)
 
-    def _acquire_handle(self, rdef: RuntimeDef, key: str):
+    def _acquire_handle(self, rdef: RuntimeDef, key: str, widx: int):
         """(handle, cold, prewarmed, err) for one warm instance; LRU
-        insert on cold.  ``prewarmed`` is True on the first hit against a
-        control-plane-installed handle (policy-attributable warmth)."""
+        insert on cold, built on worker ``widx``'s device.  ``prewarmed``
+        is True on the first hit against a control-plane-installed handle
+        (policy-attributable warmth)."""
         if rdef.setup is None:
             with self._lock:
                 self.n_cold_starts += 1
@@ -908,12 +909,15 @@ class EngineBackend(Backend):
                 self._prewarmed.discard(key)
                 return self._handles[key], False, prewarmed, None
             self.n_cold_starts += 1
+        dev = self._device_index(widx)
         try:
-            handle = rdef.setup()           # slow: jit + weights (unlocked)
+            with self._on_device(dev):
+                handle = rdef.setup()       # slow: jit + weights (unlocked)
         except Exception as e:  # noqa: BLE001 — unsuccessful event
             return None, True, False, f"cold-start failed: {e!r}"
         with self._lock:
             self._handles[key] = handle
+            self._handle_dev[key] = dev
             self._evict_over_budget_locked()
         return handle, True, False, None
 
@@ -927,8 +931,10 @@ class EngineBackend(Backend):
             inv.accelerator = acc
 
         t_acq = self.now()
-        handle, cold, prewarmed, err = self._acquire_handle(rdef, key)
+        handle, cold, prewarmed, err = self._acquire_handle(rdef, key, widx)
         cold_s = (self.now() - t_acq) if cold else 0.0  # measured setup()
+        with self._lock:
+            dev = self._handle_dev.get(key, self._device_index(widx))
         for inv in batch:
             inv.cold_start = cold
             inv.prewarmed = prewarmed
@@ -940,7 +946,7 @@ class EngineBackend(Backend):
         results: List[Any] = [None] * len(batch)
         if err is None:
             try:
-                with self._on_device(widx), self._trace_ctx(batch):
+                with self._on_device(dev), self._trace_ctx(batch):
                     results = run_batch(
                         rdef, datas,
                         dict(batch[0].config, handle=handle,
@@ -992,14 +998,21 @@ class EngineBackend(Backend):
         root = lead.span_id or f"inv{lead.inv_id}"
         return TRACER.ctx(lead.trace_id, f"{root}/a{lead.attempt}/execute")
 
-    def _on_device(self, widx: int):
-        """Pin this worker's batch to its local device (no-op without jax)."""
-        if self._devices:
+    def _resolve_devices_locked(self) -> None:
+        """This host's JAX devices (a device that fails to initialise
+        raises: the engine never runs somewhere it was not sent)."""
+        if not self._devices:
             import jax
-            return jax.default_device(
-                self._devices[widx % len(self._devices)])
-        import contextlib
-        return contextlib.nullcontext()
+            self._devices = list(jax.devices())
+
+    def _device_index(self, widx: int) -> int:
+        """Worker ``widx``'s device: workers round-robin over the host's."""
+        return widx % max(len(self._devices), 1)
+
+    def _on_device(self, dev: int):
+        """Run the enclosed setup/batch on local device ``dev``."""
+        import jax
+        return jax.default_device(self._devices[dev])
 
     # -- warm-pool introspection / control-plane actuation ---------------
     def warm_keys(self) -> List[str]:
@@ -1028,8 +1041,15 @@ class EngineBackend(Backend):
             if key in self._handles or key in self._prewarming:
                 return key in self._handles
             self._prewarming.add(key)
+            self._resolve_devices_locked()
+            # the device holding the fewest warm instances
+            load = [0] * len(self._devices)
+            for d in self._handle_dev.values():
+                load[d] += 1
+            dev = load.index(min(load))
         try:
-            handle = rdef.setup()           # slow, outside the lock
+            with self._on_device(dev):
+                handle = rdef.setup()       # slow, outside the lock
         except Exception:   # noqa: BLE001 — prewarm is best-effort
             with self._lock:
                 self._prewarming.discard(key)
@@ -1038,6 +1058,7 @@ class EngineBackend(Backend):
             self._prewarming.discard(key)
             if key not in self._handles:
                 self._handles[key] = handle
+                self._handle_dev[key] = dev
                 self._handle_idle_since[key] = self.now()
                 self._prewarmed.add(key)
                 self.n_prewarms += 1

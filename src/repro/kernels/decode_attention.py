@@ -31,6 +31,7 @@ DEFAULT_BLOCK_KV = 1024
 def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale: float, block_kv: int):
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
     n_kv = pl.num_programs(2)
     valid_len = kv_len_ref[b]
@@ -46,8 +47,8 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
         # int8-quantized caches dequantize in VMEM with per-(batch, kv-head)
         # scales (§Perf C: halves the HBM stream that dominates decode)
         q = q_ref[0, 0].astype(jnp.float32) * scale        # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]  # (bkv, hd)
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
+        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[b, h]  # (bkv, hd)
+        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[b, h]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G,bkv)
         kp = j * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -110,8 +111,10 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 pl.BlockSpec((1, 1, g_p, hd_p), lambda b, h, j, *_: (b, h, 0, 0)),
                 pl.BlockSpec((1, 1, bkv, hd_p), lambda b, h, j, *_: (b, h, j, 0)),
                 pl.BlockSpec((1, 1, bkv, hd_p), lambda b, h, j, *_: (b, h, j, 0)),
-                pl.BlockSpec((1, 1), lambda b, h, j, *_: (b, h)),
-                pl.BlockSpec((1, 1), lambda b, h, j, *_: (b, h)),
+                # scales: whole (B, KV) arrays in SMEM, read as scalars
+                # (a (1, 1) VMEM block is not tile-aligned for Mosaic)
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=pl.BlockSpec((1, 1, g_p, hd_p),
                                    lambda b, h, j, *_: (b, h, 0, 0)),

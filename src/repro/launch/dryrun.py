@@ -227,9 +227,6 @@ def _probe_cost(cfg: ModelConfig, shape: InputShape, mesh: Mesh, opts: Opts):
                                             probe=True)
         comp = jit_fn.lower(*args).compile()
         ca = comp.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):       # older jaxlib: per-device list
-            ca = ca[0] if ca else {}
-        ca = dict(ca)
         coll, per_type, counts = _cb(comp.as_text())
         return {"flops": float(ca.get("flops", 0.0)),
                 "bytes": float(ca.get("bytes accessed", 0.0)),

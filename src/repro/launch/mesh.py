@@ -6,18 +6,11 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: axes are Auto by default
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """Version-tolerant mesh construction (explicit Auto axes where the
-    installed jax supports axis_types; plain mesh otherwise)."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """Mesh over the first ``prod(shape)`` devices with explicit Auto axes."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -2,14 +2,17 @@
 invocation gateway.
 
 ``--backend sim`` (default) drives a Hardless cluster of pods on the
-discrete-event clock — real reduced-config execution inside the sim, or
-roofline-calibrated service times with ``--sim`` (full-size configs, no
-hardware needed).  ``--backend engine`` bypasses the cluster and executes
-on this host's JAX devices directly (the gateway's engine backend).
+discrete-event clock — real execution of ``--arch`` inside the sim, or
+roofline-calibrated service times with ``--sim`` (no hardware needed).
+``--arch`` names a config as published (granite-3-2b: 40 layers, d_model
+2048, bf16); ``<arch>-smoke`` is its CPU-sized variant.
+``--backend engine`` bypasses the cluster and executes on this host's
+JAX devices directly (the gateway's engine backend).
 ``--cluster N`` spawns a real multi-process deployment instead — a
 master process owner in this process plus N worker *processes* connected
 over the cluster RPC protocol (``docs/cluster.md``); runtimes are
-registered by importable spec so the workers can rebuild them.
+registered by importable spec so the workers can rebuild them; on a TPU
+host each worker process owns one chip.
 ``--workflow N`` submits N three-step *chained* workflows instead of flat
 events (each step's prompts are the previous step's generations, resolved
 through the object store — the composition layer demo).
@@ -24,14 +27,15 @@ workers — and the run demonstrates at-least-once delivery: every event
 still settles (redelivered within the retry bound or a permanent error
 record).
 
-    PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b \
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-3-2b-smoke \
         --pods 2 --events 6
     PYTHONPATH=src python -m repro.launch.serve --backend engine \
-        --workflow 2 --max-batch 4
-    PYTHONPATH=src python -m repro.launch.serve --cluster 2 --events 6
+        --arch granite-3-2b-smoke --workflow 2 --max-batch 4
+    PYTHONPATH=src python -m repro.launch.serve --cluster 2 --events 6 \
+        --arch granite-3-2b-smoke
     PYTHONPATH=src python -m repro.launch.serve --backend engine \
-        --min-warm 1 --slo-ms 2000 --tenant-quota free=2:4 \
-        --metrics-out metrics.prom
+        --arch granite-3-2b-smoke --min-warm 1 --slo-ms 2000 \
+        --tenant-quota free=2:4 --metrics-out metrics.prom
 """
 from __future__ import annotations
 
@@ -49,6 +53,7 @@ from repro.core.runtime import RuntimeDef, SimProfile
 from repro.data.tokenizer import ByteTokenizer
 from repro.gateway import (EngineBackend, Gateway, SimBackend, Workflow,
                            WorkflowStepError)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.api import make_serve_runtime
 from repro.serve.service_model import roofline_profile
 
@@ -56,7 +61,8 @@ from repro.serve.service_model import roofline_profile
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b",
-                    help="comma-separated arch ids")
+                    help="comma-separated arch ids, served as published; "
+                         "<arch>-smoke is the CPU-sized variant")
     ap.add_argument("--pods", type=int, default=None,
                     help="sim backend only (default 2)")
     ap.add_argument("--events", type=int, default=6)
@@ -83,8 +89,8 @@ def main(argv=None):
                          "worker processes (overrides --backend; "
                          "docs/cluster.md)")
     ap.add_argument("--sim", action="store_true",
-                    help="simulate full-size configs with roofline-derived "
-                         "service times instead of real reduced execution "
+                    help="simulate --arch with roofline-derived service "
+                         "times instead of real execution "
                          "(sim backend only)")
     ap.add_argument("--max-batch", type=int, default=None,
                     help="engine backend: largest micro-batch of compatible "
@@ -146,6 +152,7 @@ def main(argv=None):
         # exec away the interpreter.
         from repro.launch.tuning import maybe_reexec
         maybe_reexec("repro.launch.serve")
+    enable_compile_cache()
     if args.prefill_chunk and not args.page_size:
         ap.error("--prefill-chunk needs --page-size > 0 (chunked prefill "
                  "scatters into the paged KV pool)")
@@ -186,10 +193,11 @@ def main(argv=None):
         from repro.cluster import start_cluster
         # serve runtimes jit-compile on their cold start: generous lease
         # and heartbeat bounds so compilation never reads as death
+        # this process stays off JAX: each worker owns one chip
         handle = start_cluster(args.cluster, lease_s=300.0,
                                heartbeat_timeout_s=30.0,
                                max_batch=max_batch,
-                               ready_timeout_s=60.0)
+                               ready_timeout_s=60.0, pin_chips=True)
         gw = Gateway(handle.backend)
     elif mode == "sim":
         slice_spec = AcceleratorSpec(type=acc_type, slots=1,
@@ -262,7 +270,7 @@ def main(argv=None):
             rdef = RuntimeDef(runtime_id=f"serve-{cfg.name}",
                               profiles={acc_type: prof})
         else:
-            cfg = get_config(arch).reduced()
+            cfg = get_config(arch)
             # engine backend: make_serve_runtime's host-jax default profile
             acc_types = None if args.backend == "engine" else \
                 {acc_type: SimProfile(elat_median_s=0.4, cold_start_s=2.0)}

@@ -24,18 +24,6 @@ from repro.kernels import ops
 from repro.models.layers import mlp, mlp_specs, rms_norm, rope
 from repro.models.param import Spec
 
-if hasattr(jax, "shard_map"):           # jax >= 0.6
-    _shard_map = jax.shard_map
-else:                                   # older jax: experimental, all-manual
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                   check_vma=None):
-        # axis_names always covers every mesh axis at our call sites, so
-        # the legacy fully-manual shard_map is equivalent
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-
 Cache = Dict[str, jax.Array]
 
 
@@ -244,7 +232,8 @@ def attn_block(cfg: ModelConfig, kind: BlockKind, params, x: jax.Array, *,
                 kv_len = pos % L + 1
             else:
                 kv_len = jnp.minimum(pos + 1, L)
-            attn = ops.decode_attention(q, k_cache, v_cache, kv_len, )
+            attn = ops.decode_attention(q, k_cache, v_cache, kv_len,
+                                        impl=impl)
             new_cache = {"k": k_cache, "v": v_cache}
 
     x = x + jnp.einsum("bsh,hd->bsd", attn.reshape(B, S, H * hd), params["wo"])
@@ -267,7 +256,8 @@ def attn_block(cfg: ModelConfig, kind: BlockKind, params, x: jax.Array, *,
             F = ck.shape[1]
         if mode == "decode":
             cattn = ops.decode_attention(qc, ck, cv,
-                                         jnp.full((B,), F, jnp.int32))
+                                         jnp.full((B,), F, jnp.int32),
+                                         impl=impl)
         else:
             cattn = ops.flash_attention(qc, ck, cv, causal=False, impl=impl)
         x = x + jnp.einsum("bsh,hd->bsd", cattn.reshape(B, S, H * hd),
@@ -379,7 +369,7 @@ def moe_ffn(cfg: ModelConfig, params, h: jax.Array, *,
 
     wg_spec = P(None, None, model_axis)      # (E, d, f/m) column-parallel
     wd_spec = P(None, model_axis, None)      # (E, f/m, d) row-parallel
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_axes), P(), wg_spec, wg_spec, wd_spec),
         out_specs=(P(data_axes), P()),
@@ -832,7 +822,7 @@ def _moe_ffn_a2a(cfg: ModelConfig, params, h: jax.Array, mesh, data_axes,
         return out_full, aux
 
     wspec = P("model")   # expert dim sharded: one expert per chip
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(tuple(data_axes)), P(), wspec, wspec, wspec),
         out_specs=(P(tuple(data_axes)), P()),

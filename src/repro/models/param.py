@@ -8,6 +8,7 @@ lowering in the multi-pod dry-run.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Optional, Tuple
 
 import jax
@@ -47,7 +48,10 @@ def init_params(specs, key: jax.Array, dtype: str):
     leaves = []
     for path, spec in flat:
         path_str = "/".join(str(p) for p in path)
-        k = jax.random.fold_in(key, np.uint32(hash(path_str) & 0x7FFFFFFF))
+        # crc32, not hash(): str hashes are salted per process, and
+        # every process must draw the same weights from the same key
+        salt = zlib.crc32(path_str.encode()) & 0x7FFFFFFF
+        k = jax.random.fold_in(key, np.uint32(salt))
         dt = jnp.dtype(spec.dtype or dtype)
         if spec.init == "zeros":
             arr = jnp.zeros(spec.shape, dt)

@@ -2,37 +2,19 @@
 
 :func:`jax_profile` wraps a region in a ``jax.profiler.TraceAnnotation``
 so serving-engine steps show up named inside a JAX/XLA profiler capture
-(``jax.profiler.trace(...)`` → TensorBoard/Perfetto).  It degrades to a
-no-op when JAX (or its profiler) is unavailable, so call sites never
-need to guard the import.
+(``jax.profiler.trace(...)`` → TensorBoard/Perfetto).  JAX is imported
+at first use, so importing :mod:`repro.obs` (cluster parents, sleep
+workers) never loads it.
 """
 from __future__ import annotations
 
 import contextlib
 from typing import Any, Iterator
 
-_ANNOTATION = None
-_RESOLVED = False
-
-
-def _resolve():
-    global _ANNOTATION, _RESOLVED
-    if not _RESOLVED:
-        _RESOLVED = True
-        try:
-            from jax.profiler import TraceAnnotation
-            _ANNOTATION = TraceAnnotation
-        except Exception:       # jax absent or profiler API moved
-            _ANNOTATION = None
-    return _ANNOTATION
-
 
 @contextlib.contextmanager
 def jax_profile(name: str, **kwargs: Any) -> Iterator[None]:
     """Annotate the enclosed region in any active JAX profiler capture."""
-    annotation = _resolve()
-    if annotation is None:
-        yield
-        return
-    with annotation(name, **kwargs):
+    from jax.profiler import TraceAnnotation
+    with TraceAnnotation(name, **kwargs):
         yield

@@ -17,8 +17,11 @@ The distributed contract under test (docs/cluster.md):
 * :class:`ClusterBackend` is transport-agnostic — the in-process
   transport drives the same master surface the RPC transport does.
 """
+import os
 import re
 import socket
+import subprocess
+import sys
 import threading
 import time
 
@@ -35,6 +38,7 @@ from repro.gateway import (EngineBackend, Gateway,
 
 EXHAUSTED_RE = re.compile(r"^retries exhausted after \d+ attempt\(s\): ")
 
+SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 SLEEP_SPEC = "repro.cluster.runtimes:sleep_runtime"
 ADD_SPEC = "repro.cluster.runtimes:add_runtime"
 
@@ -139,7 +143,12 @@ def test_sigkill_without_retries_settles_exhausted_error_records():
         rid = h.backend.register_spec(
             SLEEP_SPEC, {"sleep_s": 5.0, "max_attempts": 1})
         fut = gw.invoke(rid, {"i": 0})
-        time.sleep(0.3)                 # the lone worker is mid-sleep
+        # kill only once the lone worker holds the lease (mid-sleep): a
+        # kill before the take would leave the event queued, not lost
+        deadline = time.monotonic() + 60.0
+        while h.master.op_stats()["leased"] < 1:
+            assert time.monotonic() < deadline, "the worker never took it"
+            time.sleep(0.02)
         assert h.launcher.kill(0)
         with pytest.raises(InvocationRetriesExhausted):
             fut.result()
@@ -286,3 +295,47 @@ def test_workflow_chain_composes_across_worker_processes():
         assert {i.step for i in tagged} == {"s1", "s2", "s3"}
     finally:
         h.close()
+
+
+# ------------------------------------------------------- one chip each
+def test_launcher_gives_each_jax_worker_its_own_chip(monkeypatch):
+    from repro.cluster import backend as cb
+    monkeypatch.setattr(cb, "host_tpu_chips", lambda: 2)
+    launcher = cb.WorkerLauncher("127.0.0.1:1", pin_chips=True)
+    assert launcher._free_chips(2) == [0, 1]
+    env = launcher._env(1)
+    assert env["TPU_VISIBLE_CHIPS"] == "1"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    with pytest.raises(RuntimeError, match="2 TPU chip"):
+        launcher.spawn(3)           # refused before any process starts
+    assert launcher.alive() == []
+    # workers that serve no JAX are held off the chips altogether
+    plain = cb.WorkerLauncher("127.0.0.1:1")
+    assert plain._free_chips(3) == [None] * 3
+    assert plain._env()["JAX_PLATFORMS"] == "cpu"
+    assert "TPU_VISIBLE_CHIPS" not in plain._env()
+
+
+def test_host_without_tpu_pins_nothing(monkeypatch):
+    from repro.cluster import backend as cb
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert cb.host_tpu_chips() == 0
+    launcher = cb.WorkerLauncher("127.0.0.1:1", pin_chips=True)
+    assert launcher._free_chips(8) == [None] * 8
+
+
+def test_serve_cluster_parent_never_initialises_jax():
+    """``launch/serve.py --cluster``: real generation in a worker process
+    while the parent (master + client) stays off every JAX backend."""
+    code = ("from jax._src import xla_bridge\n"
+            "from repro.launch.serve import main\n"
+            "rc = main(['--cluster', '1', '--arch', 'granite-3-2b-smoke',\n"
+            "           '--events', '2', '--max-new-tokens', '2'])\n"
+            "print('rc', rc, 'jax', xla_bridge.backends_are_initialized())\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=SRC)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "2/2 events succeeded" in out.stdout
+    assert out.stdout.strip().splitlines()[-1] == "rc 0 jax False"

@@ -107,3 +107,54 @@ def test_dryrun_single_combo_subprocess():
         env=env, capture_output=True, text=True, timeout=560)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "1 ok, 0 skipped, 0 errors" in out.stdout
+
+
+def test_engine_backend_builds_and_runs_each_instance_on_its_workers_device():
+    """One worker per device: a cold start builds the warm instance on its
+    worker's device, a prewarm on the device holding the fewest instances,
+    and every batch runs where its instance lives."""
+    out = run_sub("""
+        import threading
+        import jax, jax.numpy as jnp
+        from repro.core.runtime import HOST_ACC, RuntimeDef, SimProfile
+        from repro.gateway import EngineBackend, Gateway
+
+        gate = threading.Barrier(4, timeout=60)
+
+        def make(rid, wait):
+            def setup():
+                return jnp.zeros(4)
+            def fn(data, config):
+                if wait:
+                    gate.wait()     # all four run at once: four workers
+                h = config["handle"]
+                ran = (h + 1).devices()
+                return {"handle": h.devices().pop().id,
+                        "ran": ran.pop().id}
+            return RuntimeDef(runtime_id=rid, fn=fn, setup=setup,
+                              profiles={HOST_ACC: SimProfile(0.01, 0.0)})
+
+        backend = EngineBackend(max_batch=1)
+        gw = Gateway(backend)
+        for i in range(4):
+            gw.register(make(f"cold{i}", True))
+        for i in range(2):
+            gw.register(make(f"pre{i}", False))
+        assert len(jax.devices()) == 4
+        futs = [gw.invoke(f"cold{i}", {"x": i}) for i in range(4)]
+        res = [f.result() for f in futs]
+        nodes = [f.invocation.node for f in futs]
+        assert sorted(nodes) == [f"local/w{w}" for w in range(4)], nodes
+        for f, r in zip(futs, res):
+            w = int(f.invocation.node.rsplit("w", 1)[1])
+            assert r == {"handle": w, "ran": w}, (f.invocation.node, r)
+        # prewarms land on the least-loaded device, then run there
+        backend.max_warm = 8
+        assert backend.prewarm("pre0") and backend.prewarm("pre1")
+        got = [gw.invoke(f"pre{i}", {}).result() for i in range(2)]
+        assert [g["handle"] for g in got] == [0, 1], got
+        assert all(g["ran"] == g["handle"] for g in got), got
+        backend.shutdown()
+        print("ok")
+    """, devices=4, timeout=300)
+    assert "ok" in out

@@ -1,4 +1,7 @@
 """Unit/property tests for model building blocks."""
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +19,7 @@ from repro.models.param import abstract_params
 from repro.models.sharding import spec_for
 
 rng = np.random.default_rng(0)
+SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 # ---------------------------------------------------------------- layers
@@ -74,6 +78,37 @@ def test_param_init_deterministic_and_path_stable():
     p2 = M.init_model_params(cfg, jax.random.PRNGKey(7))
     for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
         assert bool(jnp.all(a == b))
+
+
+def test_param_init_same_in_every_process():
+    """Weights follow the seed alone: processes with differently salted
+    str hashes (cluster workers) draw the same parameters."""
+    code = ("import jax, numpy as np\n"
+            "from repro.configs import get_config\n"
+            "from repro.models import model as M\n"
+            "p = M.init_model_params(get_config('granite-3-2b-smoke'),\n"
+            "                        jax.random.PRNGKey(0))\n"
+            "print(repr(sum(float(np.abs(np.asarray(l, np.float64)).sum())\n"
+            "               for l in jax.tree.leaves(p))))\n")
+    sums = []
+    for salt in ("1", "2"):
+        env = dict(os.environ, PYTHONHASHSEED=salt, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=SRC)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        sums.append(out.stdout.strip())
+    assert sums[0] == sums[1]
+
+
+def test_smoke_arch_ids_resolve_to_reduced_configs():
+    full = get_config("granite-3-2b")
+    assert (full.n_layers, full.d_model, full.dtype) == (40, 2048,
+                                                         "bfloat16")
+    smoke = get_config("granite-3-2b-smoke")
+    assert smoke == full.reduced() and smoke.name == "granite-3-2b-smoke"
+    with pytest.raises(KeyError):
+        get_config("no-such-arch-smoke")
 
 
 def test_abstract_params_match_init_shapes():

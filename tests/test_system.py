@@ -1,6 +1,9 @@
 """End-to-end behaviour: the Hardless control plane executing REAL JAX
 model serving as runtime instances (cold start = jit + weights), plus
 metrics plumbing."""
+import os
+import subprocess
+import sys
 
 from repro.configs import get_config
 from repro.core.cluster import Cluster
@@ -8,6 +11,8 @@ from repro.core.accelerator import AcceleratorSpec
 from repro.core.events import Invocation
 from repro.core.runtime import SimProfile
 from repro.serve.api import make_serve_runtime
+
+SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def make_cluster():
@@ -57,3 +62,39 @@ def test_real_execution_elat_measured():
     inv = cl.metrics.completed[0]
     assert inv.elat is not None and inv.elat > 0
     assert inv.rlat >= inv.elat
+
+
+def _cache_probe(env_dir, tmp_path):
+    """Run the entry-point helper in a fresh process, compile once, and
+    report the directory used plus the entries under ``env_dir``."""
+    code = ("import os, sys, jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "path = enable_compile_cache()\n"
+            "assert os.environ['JAX_COMPILATION_CACHE_DIR'] == path\n"
+            "assert jax.config.jax_compilation_cache_dir == path\n"
+            "if len(sys.argv) > 1:\n"
+            "    jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64)))"
+            ".block_until_ready()\n"
+            "print(path)\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=SRC,
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    argv = [sys.executable, "-c", code]
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+        argv.append("compile")
+    out = subprocess.run(argv, env=env, cwd=tmp_path, capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_placed_from_outside_or_fixed_in_checkout(tmp_path):
+    placed = tmp_path / "placed"
+    assert _cache_probe(placed, tmp_path) == str(placed)
+    assert any(placed.iterdir())            # entries land there
+    assert sorted(p.name for p in tmp_path.iterdir()) == ["placed"]
+    # unset: a fixed path inside the checkout, whatever the cwd
+    root = os.path.dirname(SRC)
+    assert _cache_probe(None, tmp_path) == os.path.join(root, ".jax_cache")
