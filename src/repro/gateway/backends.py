@@ -743,7 +743,8 @@ class EngineBackend(Backend):
 
     def _worker_loop(self, widx: int) -> None:
         while True:
-            with self._lock:
+            with self._lock, TRACER.phase("gateway.wait",
+                                          queued=self._n_pending):
                 batch = None
                 while batch is None:
                     if self._shutdown or widx >= self._target_workers:
@@ -762,7 +763,8 @@ class EngineBackend(Backend):
                     self._crash_widx.discard(widx)
                     return
             try:
-                self._execute_batch(widx, batch)
+                with TRACER.phase("gateway.batch", size=len(batch)):
+                    self._execute_batch(widx, batch)
             except Exception as e:  # noqa: BLE001 — never kill the worker
                 self._settle_failed(batch, f"engine dispatcher error: {e!r}")
             finally:
@@ -959,15 +961,16 @@ class EngineBackend(Backend):
         # large result must not stall submit() or the other workers); the
         # events only become visible as settled (r_end) under the lock
         errs: List[Optional[str]] = []
-        for inv, result in zip(batch, results):
-            inv.e_start, inv.e_end = e_start, e_end
-            inv_err = err
-            try:
-                self.store.persist_outcome(inv, result, inv_err)
-            except Exception as e:  # noqa: BLE001 — unserializable result
-                inv_err = f"result persist failed: {e!r}"
-                self.store.persist_outcome(inv, None, inv_err)
-            errs.append(inv_err)
+        with TRACER.phase("gateway.persist"):
+            for inv, result in zip(batch, results):
+                inv.e_start, inv.e_end = e_start, e_end
+                inv_err = err
+                try:
+                    self.store.persist_outcome(inv, result, inv_err)
+                except Exception as e:  # noqa: BLE001 — unserializable
+                    inv_err = f"result persist failed: {e!r}"
+                    self.store.persist_outcome(inv, None, inv_err)
+                errs.append(inv_err)
 
         with self._lock:
             self.n_batches += 1
@@ -983,9 +986,7 @@ class EngineBackend(Backend):
                 inv.error = inv_err
                 self.metrics.record(inv)
                 if TRACER.enabled:
-                    TRACER.record_invocation(
-                        inv, cold_s=cold_s,
-                        batch_window_s=self.batch_wait_s)
+                    TRACER.record_invocation(inv, cold_s=cold_s)
 
     def _trace_ctx(self, batch: List[Invocation]):
         """Trace context for the batch's ``run_batch`` call: serving-engine

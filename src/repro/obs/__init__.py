@@ -10,7 +10,6 @@ gate on ``TRACER.enabled`` before touching anything else.
     obs.export("trace.json")            # load at https://ui.perfetto.dev
 """
 from repro.obs.export import to_trace_events, write_trace
-from repro.obs.profile import jax_profile
 from repro.obs.tracer import (ABANDONED, ERROR, OK, REJECTED, SPAN_NAMES,
                               Span, Tracer)
 from repro.obs.validate import validate_trace, validate_trace_file
@@ -47,5 +46,5 @@ __all__ = [
     "ABANDONED", "ERROR", "OK", "REJECTED", "SPAN_NAMES", "Span", "Tracer",
     "TRACER", "get_tracer", "enable", "disable", "reset", "export",
     "to_trace_events", "write_trace", "validate_trace",
-    "validate_trace_file", "jax_profile",
+    "validate_trace_file",
 ]

@@ -8,7 +8,6 @@ REnd life, decomposed into children that partition that interval exactly
     invocation                      [r_start, r_end]
       submit                        [r_start, r_start]      (instant)
       queue_wait                    [r_start, n_start]
-        batch_wait                  [n_start - window, n_start]
       dispatch                      [n_start, e_start]
         cold_start                  [n_start, n_start + cold_s]
       execute                       [e_start, e_end]
@@ -31,6 +30,13 @@ state still agree on parent links.  Workflow steps share one trace
 keeps the original trace id, so its spans (and the ``abandoned``
 closure of the dead attempt) link back to the same tree.
 
+Beside the tree, :meth:`Tracer.phase` marks what a host thread is doing
+(an engine step's phases, the dispatcher's wait): one closed span on the
+tracer's clock and, in the same stroke, a ``jax.profiler`` annotation
+whose ``t`` argument is the span's start, so a device trace can be put on
+the tracer's clock.  While enabled the tracer also hooks ``gc.callbacks``
+and records every collection as a ``gc`` phase.
+
 Cheap when off: the module-level tracer starts disabled and every
 emission path is gated on a single ``enabled`` attribute check — no
 locks, no allocation, no clock reads.
@@ -38,7 +44,9 @@ locks, no allocation, no clock reads.
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -53,9 +61,43 @@ ABANDONED = "abandoned"
 # the span taxonomy (docs/observability.md documents each entry)
 SPAN_NAMES = (
     "workflow", "invocation", "submit", "queue_wait", "admission",
-    "cold_start", "batch_wait", "dispatch", "execute", "prefill",
+    "cold_start", "dispatch", "execute", "prefill",
     "prefill_chunk", "decode", "store_put", "settle", "attempt",
 )
+
+_NOOP = contextlib.nullcontext()
+
+
+def _annotation(name: str, t: float, attrs: Dict[str, Any]):
+    """An entered ``jax.profiler.TraceAnnotation`` carrying ``t``, or None
+    in a process that has not loaded JAX (no profiler can run there, and
+    a sleep worker or a cluster parent must not load it for this)."""
+    cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    if cls is None:
+        return None
+    ann = cls(name, t=t, **attrs)
+    ann.__enter__()
+    return ann
+
+
+class _Phase:
+    """The live form of :meth:`Tracer.phase`."""
+
+    __slots__ = ("tracer", "name", "attrs", "t0", "ann")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
+        self.tracer, self.name, self.attrs = tracer, name, attrs
+
+    def __enter__(self) -> None:
+        self.t0 = self.tracer.now()
+        self.ann = _annotation(self.name, self.t0, self.attrs)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        self.tracer.complete(self.name, self.t0, self.tracer.now(),
+                             status=OK if exc_type is None else ERROR,
+                             attrs=self.attrs or None)
 
 
 @dataclass(slots=True)
@@ -114,6 +156,7 @@ class Tracer:
         self._prefix = "s"
         self._lock = threading.Lock()
         self._ctx = threading.local()
+        self._gc_open = None                # (t_start, annotation, gen)
 
     # -- lifecycle -------------------------------------------------------
     def enable(self, *, clock: Optional[Callable[[], float]] = None,
@@ -130,14 +173,23 @@ class Tracer:
         if prefix is not None:
             self._prefix = prefix
         self.enabled = True
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
         return self
 
     def disable(self) -> None:
         """Stop emitting; collected spans are kept."""
         self.enabled = False
+        self._unhook_gc()
+
+    def _unhook_gc(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        self._gc_open = None
 
     def reset(self) -> None:
         """Back to pristine: disabled, empty, wall clock."""
+        self._unhook_gc()
         with self._lock:
             self.enabled = False
             self.metrics = None
@@ -224,6 +276,42 @@ class Tracer:
             if rid is not None:
                 m.observe_span(rid, sp.name, sp.t_end - sp.t_start)
 
+    # -- phases: host activity on the tracer and the profiler ------------
+    def phase(self, name: str, **attrs: Any):
+        """Context manager marking what this host thread is doing.
+
+        Off: the shared no-op context, after the one ``enabled`` check.
+        On: one closed span named ``name`` on the tracer's clock, under the
+        thread's trace context (or ``untraced``), and a ``jax.profiler``
+        annotation of the same name whose ``t`` argument is the span's
+        start — the anchor that puts a device trace on this clock.
+        """
+        if not self.enabled:
+            return _NOOP
+        return _Phase(self, name, attrs)
+
+    def _on_gc(self, stage: str, info: Dict[str, Any]) -> None:
+        """``gc.callbacks`` hook: each collection becomes a ``gc`` phase,
+        written when it ends."""
+        if not self.enabled:
+            return
+        if stage == "start":
+            t0 = self.now()
+            gen = info.get("generation")
+            self._gc_open = (t0, _annotation("gc", t0, {"generation": gen}),
+                             gen)
+            return
+        opened, self._gc_open = self._gc_open, None
+        if opened is None:
+            return
+        t0, ann, gen = opened
+        collected = info.get("collected")
+        if ann is not None:
+            ann.set_metadata(collected=collected)
+            ann.__exit__(None, None, None)
+        self.complete("gc", t0, self.now(),
+                      attrs={"generation": gen, "collected": collected})
+
     # -- thread-local context (batch execution → engine spans) -----------
     def current(self) -> Optional[Tuple[str, Optional[str]]]:
         """The innermost (trace_id, parent_span_id) pushed on this
@@ -261,7 +349,6 @@ class Tracer:
         return sid
 
     def record_invocation(self, inv, *, cold_s: float = 0.0,
-                          batch_window_s: float = 0.0,
                           emit_cold: bool = True,
                           emit_execute: bool = True) -> None:
         """Emit the settled invocation's root span plus the children that
@@ -313,10 +400,6 @@ class Tracer:
         self._emit(Span(tid, f"{pre}/submit", root, "submit", r0, r0, OK, a))
         self._emit(Span(tid, f"{pre}/queue_wait", root, "queue_wait",
                         r0, n0, OK, a))
-        if batch_window_s > 0.0:
-            self._emit(Span(tid, f"{pre}/batch_wait", f"{pre}/queue_wait",
-                            "batch_wait", max(r0, n0 - batch_window_s), n0,
-                            OK, a))
         self._emit(Span(tid, f"{pre}/dispatch", root, "dispatch",
                         n0, e0, OK, a))
         if emit_cold and inv.cold_start and cold_s > 0.0:

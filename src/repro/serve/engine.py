@@ -38,7 +38,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.data.tokenizer import EOS
 from repro.models import model as M
-from repro.obs import TRACER, jax_profile
+from repro.obs import TRACER
 from repro.serve.paging import BlockAllocator, pages_for
 
 DEFAULT_PAGE_SIZE = 16
@@ -403,7 +403,8 @@ class ServingEngine:
                         max(self.pages_per_seq, 1))
             groups.setdefault((p, C, width), []).append(slot)
         for (p, C, width), members in groups.items():
-            self._chunk_group(members, p, C, width)
+            with TRACER.phase("serve.chunk"):
+                self._chunk_group(members, p, C, width)
 
     def _trace_span(self, name: str, t0: float, tokens: int) -> None:
         """Close one engine span against the batch executor's thread-local
@@ -482,48 +483,55 @@ class ServingEngine:
         chunk, then one jitted decode step for every decoding slot (with
         page growth / preemption beforehand).  Dense: the seed behavior —
         one decode step over the active slots.
+
+        With the tracer on, the step and its phases (``serve.admit``,
+        ``serve.chunk``, ``serve.prep``, ``serve.readback``,
+        ``serve.emit``) are written to the tracer and the profiler.
         """
-        if TRACER.enabled:
-            with jax_profile("serve.step"):
-                return self._step()
-        return self._step()
+        with TRACER.phase("serve.step"):
+            return self._step()
 
     def _step(self) -> List[Request]:
         if not self.paged:
             return self._step_decode_dense()
-        while self.waiting and self.free_slots():
-            if not self.admit(self.waiting[0]):
-                break
-            self.waiting.popleft()
+        with TRACER.phase("serve.admit"):
+            while self.waiting and self.free_slots():
+                if not self.admit(self.waiting[0]):
+                    break
+                self.waiting.popleft()
         self._advance_chunks()
         return self._decode_once()
 
     def _step_decode_dense(self) -> List[Request]:
         if all(r is None for r in self.active):
             return []
-        t0 = TRACER.now() if TRACER.enabled else 0.0
-        n_active = sum(r is not None for r in self.active)
-        tokens = jnp.asarray(self.last_token, jnp.int32)[:, None]
-        pos = jnp.asarray(self.pos, jnp.int32)
+        with TRACER.phase("serve.prep"):
+            t0 = TRACER.now() if TRACER.enabled else 0.0
+            n_active = sum(r is not None for r in self.active)
+            tokens = jnp.asarray(self.last_token, jnp.int32)[:, None]
+            pos = jnp.asarray(self.pos, jnp.int32)
         logits, self.cache = self._decode(self.params, self.cache, tokens,
                                           pos)
         self.n_decode_steps += 1
-        greedy_tok = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+        with TRACER.phase("serve.readback"):
+            greedy_tok = np.asarray(jnp.argmax(logits[:, 0], axis=-1),
+                                    np.int32)
         self._trace_span("decode", t0, n_active)
 
-        finished = []
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.pos[i] += 1
-            tok = int(greedy_tok[i]) if self.greedy else \
-                self._sample_token(logits[i, 0], req)
-            self._record_token(i, req, tok)
-            if tok == EOS or len(req.output) >= req.max_new_tokens or \
-                    int(self.pos[i]) >= self.max_len - 1:
-                req.done = True
-                finished.append(req)
-                self.active[i] = None
+        with TRACER.phase("serve.emit"):
+            finished = []
+            for i, req in enumerate(self.active):
+                if req is None:
+                    continue
+                self.pos[i] += 1
+                tok = int(greedy_tok[i]) if self.greedy else \
+                    self._sample_token(logits[i, 0], req)
+                self._record_token(i, req, tok)
+                if tok == EOS or len(req.output) >= req.max_new_tokens or \
+                        int(self.pos[i]) >= self.max_len - 1:
+                    req.done = True
+                    finished.append(req)
+                    self.active[i] = None
         return finished
 
     def _decode_once(self) -> List[Request]:
@@ -531,56 +539,63 @@ class ServingEngine:
                     if self._state[i] == DECODE]
         if not decoding:
             return []
-        # page growth for this step's writes; preempt youngest on exhaustion
-        skipped = set()
-        for i in list(decoding):
-            if self._state[i] != DECODE:
-                continue                    # evicted by an earlier growth
-            while not self.allocator.ensure(i, int(self.pos[i]) + 1):
-                victim = self._pick_victim(exclude=i)
-                if victim is None:
-                    victim = i              # alone and out of pages
-                self._evict(victim)
-                if victim == i:
-                    skipped.add(i)
-                    break
-        decoding = [i for i in decoding
-                    if self._state[i] == DECODE and i not in skipped]
-        if not decoding:
-            return []
+        with TRACER.phase("serve.prep"):
+            # page growth for this step's writes; preempt youngest on
+            # exhaustion
+            skipped = set()
+            for i in list(decoding):
+                if self._state[i] != DECODE:
+                    continue                # evicted by an earlier growth
+                while not self.allocator.ensure(i, int(self.pos[i]) + 1):
+                    victim = self._pick_victim(exclude=i)
+                    if victim is None:
+                        victim = i          # alone and out of pages
+                    self._evict(victim)
+                    if victim == i:
+                        skipped.add(i)
+                        break
+            decoding = [i for i in decoding
+                        if self._state[i] == DECODE and i not in skipped]
+            if not decoding:
+                return []
 
-        mask = np.zeros((self.max_slots,), bool)
-        mask[decoding] = True
-        width = min(
-            _next_pow2(max(self.allocator.pages_used(i) for i in decoding)),
-            max(self.pages_per_seq, 1))
-        tables = np.zeros((self.max_slots, width), np.int32)
-        for i in decoding:
-            tab = self.allocator.table(i)
-            tables[i, :len(tab)] = tab
-        tokens = np.where(mask, self.last_token, 0).astype(np.int32)
-        pos = np.where(mask, self.pos, 0).astype(np.int32)
+            mask = np.zeros((self.max_slots,), bool)
+            mask[decoding] = True
+            width = min(
+                _next_pow2(max(self.allocator.pages_used(i)
+                               for i in decoding)),
+                max(self.pages_per_seq, 1))
+            tables = np.zeros((self.max_slots, width), np.int32)
+            for i in decoding:
+                tab = self.allocator.table(i)
+                tables[i, :len(tab)] = tab
+            tokens = np.where(mask, self.last_token, 0).astype(np.int32)
+            pos = np.where(mask, self.pos, 0).astype(np.int32)
 
-        t0 = TRACER.now() if TRACER.enabled else 0.0
-        logits, self.cache = self._decode_paged(
-            self.params, self.cache, jnp.asarray(tokens)[:, None],
-            jnp.asarray(pos), jnp.asarray(tables), jnp.asarray(mask))
+            t0 = TRACER.now() if TRACER.enabled else 0.0
+            args = (jnp.asarray(tokens)[:, None], jnp.asarray(pos),
+                    jnp.asarray(tables), jnp.asarray(mask))
+        logits, self.cache = self._decode_paged(self.params, self.cache,
+                                                *args)
         self.n_decode_steps += 1
-        greedy_tok = np.asarray(jnp.argmax(logits[:, 0], axis=-1), np.int32)
+        with TRACER.phase("serve.readback"):
+            greedy_tok = np.asarray(jnp.argmax(logits[:, 0], axis=-1),
+                                    np.int32)
         self._trace_span("decode", t0, len(decoding))
 
-        finished = []
-        for i in decoding:
-            req = self.active[i]
-            self.pos[i] += 1
-            tok = int(greedy_tok[i]) if self.greedy else \
-                self._sample_token(logits[i, 0], req)
-            self._record_token(i, req, tok)
-            if tok == EOS or len(req.output) >= req.max_new_tokens or \
-                    int(self.pos[i]) >= self.max_len - 1:
-                req.done = True
-                finished.append(req)
-                self._release(i)
+        with TRACER.phase("serve.emit"):
+            finished = []
+            for i in decoding:
+                req = self.active[i]
+                self.pos[i] += 1
+                tok = int(greedy_tok[i]) if self.greedy else \
+                    self._sample_token(logits[i, 0], req)
+                self._record_token(i, req, tok)
+                if tok == EOS or len(req.output) >= req.max_new_tokens or \
+                        int(self.pos[i]) >= self.max_len - 1:
+                    req.done = True
+                    finished.append(req)
+                    self._release(i)
         return finished
 
     # ------------------------------------------------------------------
@@ -613,9 +628,11 @@ class ServingEngine:
             waiting = list(requests)
             done: List[Request] = []
             while waiting or any(r is not None for r in self.active):
-                while waiting and self.free_slots():
-                    self.admit(waiting.pop(0))
-                done.extend(self._step_decode_dense())
+                with TRACER.phase("serve.step"):
+                    with TRACER.phase("serve.admit"):
+                        while waiting and self.free_slots():
+                            self.admit(waiting.pop(0))
+                    done.extend(self._step_decode_dense())
             return done
 
         for req in requests:
