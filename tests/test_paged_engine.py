@@ -261,14 +261,40 @@ def test_sampling_key_varies_with_attempt(sampled):
 # ----------------------------------------------------------------------
 # paged attention kernels: Pallas (interpret) vs the jnp oracle
 # ----------------------------------------------------------------------
-def test_paged_decode_kernel_interpret_matches_ref():
+# (KV heads, query heads per KV head, head dim): the first layout is the
+# original case; then granite-3-2b's, qwen2.5-14b's and a multi-head one
+HEAD_LAYOUTS = [(2, 2, 16), (8, 4, 64), (8, 5, 128), (4, 1, 32)]
+PAGE, P = 8, 3
+
+
+def _layout_id(layout):
+    return "kv{}-g{}-hd{}".format(*layout)
+
+
+def _paged_rows(rng, kv_lens):
+    """Block tables for sequences of ``kv_lens`` keys (0 = an inactive row,
+    whose table is all zeros) over a pool with every page id shuffled."""
+    need = [-(-n // PAGE) for n in kv_lens]
+    ids = list(1 + rng.permutation(sum(need)))
+    bt = np.zeros((len(kv_lens), P), np.int32)
+    for r, n in enumerate(need):
+        bt[r, :n] = [ids.pop() for _ in range(n)]
+    return bt, sum(need) + 1
+
+
+@pytest.mark.parametrize("layout", HEAD_LAYOUTS, ids=_layout_id)
+def test_paged_decode_kernel_interpret_matches_ref(layout):
+    """Rows: the original two, one key, a length ending on a page
+    boundary, a full block table, an inactive row (kv_len 1 on the
+    scratch page, as the engine passes it)."""
+    KV, G, hd = layout
     rng = np.random.default_rng(0)
-    B, H, KV, hd, page, npages, P = 2, 4, 2, 16, 8, 9, 3
+    kv_len = np.array([13, 20, 1, 16, P * PAGE, 1], np.int32)
+    bt, npages = _paged_rows(rng, [13, 20, 1, 16, P * PAGE, 0])
+    B, H = len(kv_len), KV * G
     q = rng.standard_normal((B, 1, H, hd), np.float32)
-    kp = rng.standard_normal((npages, page, KV, hd), np.float32)
-    vp = rng.standard_normal((npages, page, KV, hd), np.float32)
-    bt = np.array([[1, 2, 3], [4, 5, 6]], np.int32)
-    kv_len = np.array([13, 20], np.int32)
+    kp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
+    vp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
     ref_out = ops.paged_decode_attention(q, kp, vp, bt, kv_len, impl="ref")
     int_out = ops.paged_decode_attention(q, kp, vp, bt, kv_len,
                                          impl="interpret")
@@ -276,15 +302,21 @@ def test_paged_decode_kernel_interpret_matches_ref():
                                rtol=1e-4, atol=1e-5)
 
 
-def test_paged_prefill_kernel_interpret_matches_ref():
+@pytest.mark.parametrize("C", [5, 1])
+@pytest.mark.parametrize("layout", HEAD_LAYOUTS, ids=_layout_id)
+def test_paged_prefill_kernel_interpret_matches_ref(layout, C):
+    """Chunks of C queries ending at the original two lengths, at the
+    first key(s), on a page boundary, at a full block table, and an
+    inactive row (a chunk at 0 on the scratch page)."""
+    KV, G, hd = layout
     rng = np.random.default_rng(1)
-    B, C, H, KV, hd, page, npages, P = 2, 5, 4, 2, 16, 8, 9, 3
+    kv_len = np.array([13, 20, C, 16, P * PAGE, C], np.int32)
+    bt, npages = _paged_rows(rng, [13, 20, C, 16, P * PAGE, 0])
+    q_off = kv_len - C
+    B, H = len(kv_len), KV * G
     q = rng.standard_normal((B, C, H, hd), np.float32)
-    kp = rng.standard_normal((npages, page, KV, hd), np.float32)
-    vp = rng.standard_normal((npages, page, KV, hd), np.float32)
-    bt = np.array([[1, 2, 3], [4, 5, 6]], np.int32)
-    q_off = np.array([8, 15], np.int32)
-    kv_len = q_off + C
+    kp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
+    vp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
     ref_out = ops.paged_prefill_attention(q, kp, vp, bt, kv_len, q_off,
                                           impl="ref")
     int_out = ops.paged_prefill_attention(q, kp, vp, bt, kv_len, q_off,
