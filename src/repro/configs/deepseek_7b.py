@@ -1,6 +1,7 @@
 """DeepSeek-LLM 7B. [arXiv:2401.02954]
 
-Llama-architecture dense decoder, MHA-like (kv = heads = 32).
+Llama-architecture dense decoder, MHA-like (kv = heads = 32), with an
+untied output head (``tie_word_embeddings: false``).
 """
 from repro.configs.base import Family, ModelConfig, register
 
@@ -17,5 +18,6 @@ def config() -> ModelConfig:
         head_dim=128,
         d_ff=11_008,
         vocab=102_400,
-        source="arXiv:2401.02954",
+        tie_embeddings=False,
+        source="hf:deepseek-ai/deepseek-llm-7b-base",
     )

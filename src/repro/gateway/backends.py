@@ -948,11 +948,13 @@ class EngineBackend(Backend):
         results: List[Any] = [None] * len(batch)
         if err is None:
             try:
+                config = dict(batch[0].config, handle=handle,
+                              attempts=[inv.attempt for inv in batch])
+                if TRACER.enabled:
+                    config["traces"] = [self._execute_ctx(inv)
+                                        for inv in batch]
                 with self._on_device(dev), self._trace_ctx(batch):
-                    results = run_batch(
-                        rdef, datas,
-                        dict(batch[0].config, handle=handle,
-                             attempts=[inv.attempt for inv in batch]))
+                    results = run_batch(rdef, datas, config)
             except Exception as e:  # noqa: BLE001 — unsuccessful events
                 err = repr(e)
         e_end = e_start + (self.now() - t0)     # measured wall ELat
@@ -988,16 +990,24 @@ class EngineBackend(Backend):
                 if TRACER.enabled:
                     TRACER.record_invocation(inv, cold_s=cold_s)
 
+    @staticmethod
+    def _execute_ctx(inv: Invocation):
+        """(trace id, ``execute`` span id) of ``inv``, or None untraced."""
+        if inv.trace_id is None:
+            return None
+        root = inv.span_id or f"inv{inv.inv_id}"
+        return inv.trace_id, f"{root}/a{inv.attempt}/execute"
+
     def _trace_ctx(self, batch: List[Invocation]):
         """Trace context for the batch's ``run_batch`` call: serving-engine
         spans (prefill/decode) emitted during execution nest under the
-        lead invocation's ``execute`` span."""
+        lead invocation's ``execute`` span.  Each event's own context
+        rides ``config["traces"]``, for spans of one request."""
         import contextlib
-        lead = batch[0]
-        if not TRACER.enabled or lead.trace_id is None:
+        ctx = self._execute_ctx(batch[0]) if TRACER.enabled else None
+        if ctx is None:
             return contextlib.nullcontext()
-        root = lead.span_id or f"inv{lead.inv_id}"
-        return TRACER.ctx(lead.trace_id, f"{root}/a{lead.attempt}/execute")
+        return TRACER.ctx(*ctx)
 
     def _resolve_devices_locked(self) -> None:
         """This host's JAX devices (a device that fails to initialise

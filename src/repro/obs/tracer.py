@@ -12,6 +12,7 @@ REnd life, decomposed into children that partition that interval exactly
         cold_start                  [n_start, n_start + cold_s]
       execute                       [e_start, e_end]
         prefill / prefill_chunk / decode   (serving engine, tokens/s)
+        first_token                 (per request: slot taken → first token)
       store_put                     [e_end, n_end]
       settle                        [n_end, r_end]
 
@@ -62,7 +63,8 @@ ABANDONED = "abandoned"
 SPAN_NAMES = (
     "workflow", "invocation", "submit", "queue_wait", "admission",
     "cold_start", "dispatch", "execute", "prefill",
-    "prefill_chunk", "decode", "store_put", "settle", "attempt",
+    "prefill_chunk", "decode", "first_token", "store_put", "settle",
+    "attempt",
 )
 
 _NOOP = contextlib.nullcontext()

@@ -67,13 +67,14 @@ def make_serve_runtime(cfg: ModelConfig, *,
         return data["prompts"] if "prompts" in data else data["outputs"]
 
     def _requests(data: Any, max_new: int, base_id: int,
-                  attempt: int = 0) -> List[Request]:
+                  attempt: int = 0, trace=None) -> List[Request]:
         # the delivery attempt folds into each request's sampling key, so
         # an at-least-once redelivery draws fresh randomness instead of
-        # replaying the lost attempt's stream
+        # replaying the lost attempt's stream; ``trace`` is the event's
+        # trace context (traced runs), for each request's own spans
         prompts = [list(p) or [0] for p in _prompts(data)]
         return [Request(prompt=p, max_new_tokens=max_new,
-                        req_id=base_id + i, attempt=attempt)
+                        req_id=base_id + i, attempt=attempt, trace=trace)
                 for i, p in enumerate(prompts)]
 
     def fn(data: Any, config: Dict[str, Any]):
@@ -94,9 +95,12 @@ def make_serve_runtime(cfg: ModelConfig, *,
         max_new = int(config.get("max_new_tokens", 8))
         attempts = list(config.get("attempts") or [])
         attempts += [0] * (len(datas) - len(attempts))
+        traces = list(config.get("traces") or [])
+        traces += [None] * (len(datas) - len(traces))
         groups, base = [], 0
-        for data, attempt in zip(datas, attempts):
-            reqs = _requests(data, max_new, base_id=base, attempt=attempt)
+        for data, attempt, trace in zip(datas, attempts, traces):
+            reqs = _requests(data, max_new, base_id=base, attempt=attempt,
+                             trace=trace)
             base += len(reqs)
             groups.append(reqs)
         done_groups = engine.generate_many(groups)

@@ -29,7 +29,7 @@ import dataclasses
 import functools
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +60,12 @@ class Request:
     done: bool = False
     t_submit: Optional[float] = None    # wall clock, for TTFT accounting
     t_first: Optional[float] = None
+    # with the tracer on: the (trace id, parent span id) the request's
+    # ``first_token`` span closes under (set per event by the gateway's
+    # dispatcher, else the thread's context at admission), and the
+    # tracer's time when the request first took a slot
+    trace: Optional[Tuple[str, Optional[str]]] = None
+    t_admit: Optional[float] = None
 
 
 def _slot_batch_axis(path) -> int:
@@ -179,7 +185,21 @@ class ServingEngine:
         req.output.append(tok)
         if req.t_first is None:
             req.t_first = time.perf_counter()
+            if req.t_admit is not None and TRACER.enabled:
+                TRACER.complete("first_token", req.t_admit, TRACER.now(),
+                                trace=req.trace[0], parent=req.trace[1],
+                                attrs={"tokens": len(req.prompt)})
         self.last_token[slot] = tok
+
+    def _note_admission(self, req: Request) -> None:
+        """With the tracer on and a trace context to close under, stamp
+        the tracer's time when ``req`` takes a slot; a preempted request
+        keeps its first stamp, so it gets one ``first_token`` span."""
+        if TRACER.enabled and req.t_admit is None:
+            if req.trace is None:
+                req.trace = TRACER.current()
+            if req.trace is not None:
+                req.t_admit = TRACER.now()
 
     # ------------------------------------------------------------------
     # paged-cache tree surgery (leaf order fixed by tree_flatten_with_path;
@@ -334,6 +354,7 @@ class ServingEngine:
         slot = slots[0]
 
         if not self.paged:
+            self._note_admission(req)
             t0 = TRACER.now() if TRACER.enabled else 0.0
             prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
             logits, slot_cache = self._prefill(self.params,
@@ -355,6 +376,7 @@ class ServingEngine:
             return False
         self.active[slot] = req
         self._admit_order.append(slot)
+        self._note_admission(req)
         if self._chunk_ok and len(seq) > self.prefill_chunk:
             self._state[slot] = PREFILL
             self._seq[slot] = seq

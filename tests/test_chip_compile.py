@@ -14,6 +14,7 @@
 The topology is described inside a module fixture (only the worker that
 runs this file loads the TPU library), never at import.
 """
+import dataclasses
 import functools
 import importlib.util
 import math
@@ -148,33 +149,45 @@ def test_paged_kernel_wrappers_add_no_pool_pass(name, one_chip,
     assert not big, big
 
 
-# pool-slice-sized copies in the compiled decode step at the benchmark
-# cell's engine shape: XLA stores the stacked pool pages-minor, so each
-# layer copies its K and V slices to row-major before the kernel (2) and
-# back after (2), and the step ends with a copy of each stacked pool (2)
-DECODE_POOL_COPIES = 6
+# pool-slice-sized copies in the compiled decode step at each benchmark
+# cell's engine shape (architecture, layers, slots, max_len, pool tokens,
+# pinned count).  granite-3-2b: XLA stores the stacked pool pages-minor
+# (head dim 64 half-fills a lane tile), so each layer copies its K and V
+# slices to row-major before the kernel (2) and back after (2), and the
+# step ends with a copy of each stacked pool (2).  deepseek-7b-pp: head dim
+# 128 fills a lane tile and the pool is stored row-major
+# ({4,3,2,1,0}), so no per-layer copy is left; the two stacked-pool copies
+# at the step's end remain, 1.34 GB each.
+DECODE_POOL_CASES = [
+    pytest.param("granite-3-2b", 40, 4, 2048, 8192, 6, id="granite"),
+    pytest.param("deepseek-7b", 10, 4, 4096, 16384, 2, id="deepseek-7b-pp"),
+]
 
 
-def test_decode_program_pool_copies_pinned(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("arch,layers,slots,max_len,pool_tokens,pinned",
+                         DECODE_POOL_CASES)
+def test_decode_program_pool_copies_pinned(arch, layers, slots, max_len,
+                                           pool_tokens, pinned, one_chip,
+                                           no_persistent_cache):
     """The engine's decode program as the engine jits it (stacked pool,
     default layouts).  A change to how the pool is stored should lower
-    this count: lower the pin with it."""
+    these counts: lower the pins with it."""
     from repro.models import model as M
     from repro.models.param import abstract_params
     from repro.serve.engine import ServingEngine
-    slots, max_len, pool_tokens = 4, 2048, 8192
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
     pages = pool_tokens // PAGE + 1
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
 
-    params = on_chip(abstract_params(M.param_specs(CFG), CFG.dtype))
+    params = on_chip(abstract_params(M.param_specs(cfg), cfg.dtype))
     cache = on_chip(abstract_params(
-        M.paged_cache_specs(CFG, slots, max_len, pages, PAGE), CFG.dtype))
+        M.paged_cache_specs(cfg, slots, max_len, pages, PAGE), cfg.dtype))
     eng = ServingEngine.__new__(ServingEngine)
-    eng.cfg, eng.impl = CFG, "pallas"
-    eng._paged_flags = M.paged_leaf_flags(CFG, cache)
+    eng.cfg, eng.impl = cfg, "pallas"
+    eng._paged_flags = M.paged_leaf_flags(cfg, cache)
 
     def arg(shape, dt=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -185,10 +198,10 @@ def test_decode_program_pool_copies_pinned(one_chip, no_persistent_cache):
                      arg((slots, max_len // PAGE)),
                      arg((slots,), jnp.bool_)).compile().as_text()
     assert "tpu_custom_call" in hlo
-    slice_elems = pages * PAGE * CFG.n_kv_heads * CFG.hd
+    slice_elems = pages * PAGE * cfg.n_kv_heads * cfg.hd
     big = _big_passes(hlo, slice_elems)
     assert all(" copy(" in b for b in big), big
-    assert len(big) == DECODE_POOL_COPIES, big
+    assert len(big) == pinned, big
 
 
 # ----------------------------------------------------------------------
