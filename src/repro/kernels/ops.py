@@ -64,33 +64,36 @@ def decode_attention(q, k, v, kv_len, *, softmax_scale=None,
                                interpret=(impl == "interpret"))
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, *,
+def paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len, layer, *,
                            softmax_scale=None, impl: Optional[str] = None):
-    """Single-step attention through per-sequence block tables (the paged
-    serving engine's decode hot path)."""
+    """Single-step attention through per-sequence block tables against
+    layer ``layer`` of the stacked pools (the paged serving engine's
+    decode hot path)."""
     impl = _resolve(impl)
     if impl in ("xla", "ref", "xla_full", "xla_noattn"):
         return ref.paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                          kv_len, softmax_scale=softmax_scale)
+                                          kv_len, layer,
+                                          softmax_scale=softmax_scale)
     from repro.kernels import decode_attention as da
     return da.paged_decode_attention(q, k_pool, v_pool, block_tables, kv_len,
-                                     softmax_scale=softmax_scale,
+                                     layer, softmax_scale=softmax_scale,
                                      interpret=(impl == "interpret"))
 
 
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, kv_len,
-                            q_offset, *, softmax_scale=None,
+                            q_offset, layer, *, softmax_scale=None,
                             impl: Optional[str] = None):
-    """Chunked-prefill attention through block tables (chunk K/V already
-    scattered into the pool before the call)."""
+    """Chunked-prefill attention through block tables against layer
+    ``layer`` of the stacked pools (chunk K/V already scattered into the
+    pool before the call)."""
     impl = _resolve(impl)
     if impl in ("xla", "ref", "xla_full", "xla_noattn"):
         return ref.paged_prefill_attention(q, k_pool, v_pool, block_tables,
-                                           kv_len, q_offset,
+                                           kv_len, q_offset, layer,
                                            softmax_scale=softmax_scale)
     from repro.kernels import decode_attention as da
     return da.paged_prefill_attention(q, k_pool, v_pool, block_tables,
-                                      kv_len, q_offset,
+                                      kv_len, q_offset, layer,
                                       softmax_scale=softmax_scale,
                                       interpret=(impl == "interpret"))
 

@@ -165,33 +165,38 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
-def gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """Gather a paged KV pool into per-sequence contiguous caches.
+def gather_pages(pool: jax.Array, block_tables: jax.Array, layer,
+                 hd: int) -> jax.Array:
+    """Gather layer ``layer`` of a paged KV pool into per-sequence
+    contiguous caches.
 
-    pool: (num_pages, page, KV, hd); block_tables: (B, P) physical page ids
-    (0 = the reserved scratch page for unmapped logical pages).
-    Returns (B, P*page, KV, hd) — logical position p of sequence b lives at
-    row p of its gather, so a plain ``kv_len`` mask recovers validity.
+    pool: (n_layers, num_pages, page, KV * hd); block_tables: (B, P)
+    physical page ids (0 = the reserved scratch page for unmapped logical
+    pages).  Returns (B, P*page, KV, hd) — logical position p of sequence
+    b lives at row p of its gather, so a plain ``kv_len`` mask recovers
+    validity.
     """
     B, P = block_tables.shape
-    _, page, KV, hd = pool.shape
-    return pool[block_tables].reshape(B, P * page, KV, hd)
+    page, width = pool.shape[2], pool.shape[3]
+    return pool[layer, block_tables].reshape(B, P * page, width // hd, hd)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_tables: jax.Array,
-                           kv_len: jax.Array, *,
+                           kv_len: jax.Array, layer, *,
                            softmax_scale: Optional[float] = None
                            ) -> jax.Array:
-    """Single-step GQA attention through block tables.
+    """Single-step GQA attention through block tables, against layer
+    ``layer`` of (n_layers, num_pages, page, KV * hd) pools.
 
     Semantically ``decode_attention`` over the gathered pages: the mapped
     prefix [0, kv_len) of each sequence's gather is its KV history in
     order, everything past it (partial last page + scratch-page rows) is
     masked by ``kv_len``.
     """
-    return decode_attention(q, gather_pages(k_pool, block_tables),
-                            gather_pages(v_pool, block_tables),
+    hd = q.shape[-1]
+    return decode_attention(q, gather_pages(k_pool, block_tables, layer, hd),
+                            gather_pages(v_pool, block_tables, layer, hd),
                             kv_len, softmax_scale=softmax_scale)
 
 
@@ -231,14 +236,16 @@ def chunk_prefill_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def paged_prefill_attention(q: jax.Array, k_pool: jax.Array,
                             v_pool: jax.Array, block_tables: jax.Array,
-                            kv_len: jax.Array, q_offset: jax.Array, *,
+                            kv_len: jax.Array, q_offset: jax.Array, layer, *,
                             softmax_scale: Optional[float] = None
                             ) -> jax.Array:
-    """Chunked-prefill attention through block tables (chunk K/V already
-    scattered into the pool pages before the call)."""
+    """Chunked-prefill attention through block tables against layer
+    ``layer`` (chunk K/V already scattered into the pool pages before the
+    call)."""
+    hd = q.shape[-1]
     return chunk_prefill_attention(
-        q, gather_pages(k_pool, block_tables),
-        gather_pages(v_pool, block_tables),
+        q, gather_pages(k_pool, block_tables, layer, hd),
+        gather_pages(v_pool, block_tables, layer, hd),
         kv_len, q_offset, softmax_scale=softmax_scale)
 
 

@@ -120,7 +120,7 @@ def _ffn(cfg: ModelConfig, params, x: jax.Array,
 
 
 def attn_block(cfg: ModelConfig, kind: BlockKind, params, x: jax.Array, *,
-               mode: str, layer_idx: int = 0,
+               mode: str, layer=0,
                cache: Optional[Cache] = None,
                pos: Optional[jax.Array] = None,
                causal: bool = True, cross_x: Optional[jax.Array] = None,
@@ -133,9 +133,11 @@ def attn_block(cfg: ModelConfig, kind: BlockKind, params, x: jax.Array, *,
     ``cache_len``: total decode-cache capacity to allocate at prefill time
     (≥ prompt length; defaults to the prompt length).
     ``block_tables``: (B, P) physical page ids — present iff this block's
-    K/V cache is a paged pool (num_pages, page, KV, hd) instead of the
-    dense per-slot (B, L, KV, hd); only global attention pages (ring
-    caches are already O(window) per slot).
+    K/V cache is a stack of paged pools (n_layers, num_pages, page,
+    KV * hd) instead of the dense per-slot (B, L, KV, hd); only global
+    attention pages (ring caches are already O(window) per slot).
+    ``layer``: this block's index into that stack; its new K/V rows are
+    written there in place and its attention reads its pages there.
     """
     B, S, d = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -183,17 +185,19 @@ def attn_block(cfg: ModelConfig, kind: BlockKind, params, x: jax.Array, *,
         q, k, v = _qkv(cfg, params, h)
         q = rope(q, tokpos[None, :], cfg.rope_theta)
         k = rope(k, tokpos[None, :], cfg.rope_theta)
-        page = cache["k"].shape[1]
+        page = cache["k"].shape[2]
         phys = jnp.take_along_axis(
             block_tables, jnp.broadcast_to((tokpos // page)[None], (B, S)),
             axis=1)                                         # (B, S)
         off = jnp.broadcast_to((tokpos % page)[None], (B, S))
-        k_pool = cache["k"].at[phys, off].set(k.astype(cache["k"].dtype))
-        v_pool = cache["v"].at[phys, off].set(v.astype(cache["v"].dtype))
+        k_pool = cache["k"].at[layer, phys, off].set(
+            k.reshape(B, S, KV * hd).astype(cache["k"].dtype))
+        v_pool = cache["v"].at[layer, phys, off].set(
+            v.reshape(B, S, KV * hd).astype(cache["v"].dtype))
         kv_len = jnp.full((B,), pos + S, jnp.int32)
         q_off = jnp.full((B,), pos, jnp.int32)
         attn = ops.paged_prefill_attention(q, k_pool, v_pool, block_tables,
-                                           kv_len, q_off, impl=impl)
+                                           kv_len, q_off, layer, impl=impl)
         new_cache = {"k": k_pool, "v": v_pool}
     else:  # decode
         assert cache is not None and pos is not None
@@ -201,20 +205,21 @@ def attn_block(cfg: ModelConfig, kind: BlockKind, params, x: jax.Array, *,
         q = rope(q, pos[:, None], cfg.rope_theta)
         k_new = rope(k_new, pos[:, None], cfg.rope_theta)
         if block_tables is not None and not (window or chunk):
-            # paged: this token's K/V lands at (page[pos // page], pos %
-            # page) of the shared pool; attention gathers back through the
-            # table. Inactive engine rows carry an all-zeros table (the
-            # reserved scratch page), so their writes are harmless.
-            page = cache["k"].shape[1]
+            # paged: this token's K/V lands at (layer, page[pos // page],
+            # pos % page) of the stacked pool; attention gathers back
+            # through the table. Inactive engine rows carry an all-zeros
+            # table (the reserved scratch page), so their writes are
+            # harmless.
+            page = cache["k"].shape[2]
             phys = jnp.take_along_axis(block_tables,
                                        (pos // page)[:, None], axis=1)[:, 0]
             off = pos % page
-            k_cache = cache["k"].at[phys, off].set(
-                k_new[:, 0].astype(cache["k"].dtype))
-            v_cache = cache["v"].at[phys, off].set(
-                v_new[:, 0].astype(cache["v"].dtype))
+            k_cache = cache["k"].at[layer, phys, off].set(
+                k_new[:, 0].reshape(B, KV * hd).astype(cache["k"].dtype))
+            v_cache = cache["v"].at[layer, phys, off].set(
+                v_new[:, 0].reshape(B, KV * hd).astype(cache["v"].dtype))
             attn = ops.paged_decode_attention(q, k_cache, v_cache,
-                                              block_tables, pos + 1,
+                                              block_tables, pos + 1, layer,
                                               impl=impl)
             new_cache = {"k": k_cache, "v": v_cache}
         else:

@@ -142,19 +142,24 @@ def paged_cache_specs(cfg: ModelConfig, batch: int, seq_len: int,
                       num_pages: int, page_size: int,
                       kv_dtype: Optional[str] = None) -> Dict[str, Any]:
     """Like :func:`cache_specs`, with global-attention k/v replaced by
-    pooled page arrays. Layers stacked in one period share the pool SHAPE
-    but each owns its pages (leading ``n_periods`` axis), addressed by the
-    same block table."""
+    pooled page arrays.  A layer's pool is (num_pages, page_size, KV * hd):
+    a page is its tokens' rows of every KV head side by side, which the
+    TPU stores row-major at any head dim (no lane padding, no re-layout).
+    Layers stacked in one period share the pool SHAPE but each owns its
+    pages (leading ``n_periods`` axis), addressed by the same block
+    table; a remainder layer's pool is a one-layer stack, so every pool
+    is read as (layer, page) of a stack."""
     n_periods, rem = _layout(cfg)
     cross = cfg.is_encdec
     KV, hd = cfg.n_kv_heads, cfg.hd
 
-    def bcs(kind):
+    def bcs(kind, stacked: bool):
         s = dict(_block_cache_specs(cfg, kind, batch, seq_len, cross))
         if kind == BlockKind.ATTN:
-            pool = Spec((num_pages, page_size, KV, hd),
-                        (None, None, "kv", None), init="zeros")
-            s["k"], s["v"] = pool, pool
+            pool = Spec((num_pages, page_size, KV * hd), (None, None, "kv"),
+                        init="zeros")
+            pools = {"k": pool, "v": pool}
+            s.update(pools if stacked else _stack(pools, 1))
         if kv_dtype:
             s = {k: (dataclasses.replace(v, dtype=kv_dtype)
                      if k in ("k", "v") else v) for k, v in s.items()}
@@ -163,11 +168,11 @@ def paged_cache_specs(cfg: ModelConfig, batch: int, seq_len: int,
     specs: Dict[str, Any] = {}
     if n_periods:
         specs["blocks"] = {
-            f"p{i}": _stack(bcs(kind), n_periods)
+            f"p{i}": _stack(bcs(kind, True), n_periods)
             for i, kind in enumerate(cfg.pattern)}
     if rem:
         specs["rem"] = {
-            f"r{j}": bcs(cfg.pattern[j % len(cfg.pattern)])
+            f"r{j}": bcs(cfg.pattern[j % len(cfg.pattern)], False)
             for j in range(rem)}
     return specs
 
@@ -215,11 +220,12 @@ def chunked_prefill_supported(cfg: ModelConfig) -> bool:
 # Block dispatch
 # ----------------------------------------------------------------------
 def _apply_block(cfg, kind: BlockKind, params, x, *, mode, cache, pos,
-                 cross_x, cache_len, impl, block_tables=None):
+                 cross_x, cache_len, impl, block_tables=None, layer=0):
     if kind in (BlockKind.ATTN, BlockKind.LOCAL_ATTN, BlockKind.CHUNKED_ATTN):
         return B.attn_block(cfg, kind, params, x, mode=mode, cache=cache,
                             pos=pos, cross_x=cross_x, cache_len=cache_len,
-                            impl=impl, block_tables=block_tables)
+                            impl=impl, block_tables=block_tables,
+                            layer=layer)
     if kind == BlockKind.RGLRU:
         return B.rglru_block(cfg, params, x, mode=mode, cache=cache, impl=impl)
     if kind == BlockKind.MLSTM:
@@ -304,23 +310,35 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
 
     n_periods, rem = _layout(cfg)
     aux0 = jnp.float32(0.0)
+    # paged global-attention pools ride the layer loop's carry whole: each
+    # layer writes its tokens' rows in place and its attention reads its
+    # own pages by index, so no layer's pool is sliced out or written back
+    paged = block_tables is not None and mode in ("decode", "chunk")
+    pooled = [f"p{i}" for i, kind in enumerate(cfg.pattern)
+              if paged and kind == BlockKind.ATTN]
 
     def period_body(carry, xs):
-        x, aux = carry
+        x, aux, pools, layer = carry
         x = constrain(x, "batch", "seq", "embed")
         p_params, p_cache = xs
-        new_caches = {}
+        new_caches, new_pools = {}, {}
         for i, kind in enumerate(cfg.pattern):
-            c = p_cache[f"p{i}"] if p_cache is not None else None
-            x, nc, a = _apply_block(cfg, kind, p_params[f"p{i}"], x,
+            name = f"p{i}"
+            c = p_cache[name] if p_cache is not None else None
+            if name in pools:
+                c = {**c, **pools[name]}
+            x, nc, a = _apply_block(cfg, kind, p_params[name], x,
                                     mode=mode, cache=c, pos=pos,
                                     cross_x=cross_x, cache_len=cache_len,
-                                    impl=impl, block_tables=block_tables)
+                                    impl=impl, block_tables=block_tables,
+                                    layer=layer)
             if nc is not None:
-                new_caches[f"p{i}"] = nc
+                if name in pools:
+                    new_pools[name] = {k: nc.pop(k) for k in ("k", "v")}
+                new_caches[name] = nc
             aux = aux + a
         x = constrain(x, "batch", "seq", "embed")
-        return (x, aux), (new_caches or None)
+        return (x, aux, new_pools, layer + 1), (new_caches or None)
 
     body = period_body
     if remat:
@@ -334,19 +352,27 @@ def forward(cfg: ModelConfig, params, batch: Dict[str, jax.Array], *,
     new_cache: Dict[str, Any] = {}
     if n_periods:
         p_cache = cache["blocks"] if cache is not None else None
+        pools = {n: {k: p_cache[n][k] for k in ("k", "v")} for n in pooled}
+        if pooled:
+            p_cache = {n: {k: v for k, v in c.items()
+                           if not (n in pools and k in ("k", "v"))}
+                       for n, c in p_cache.items()}
         xs = (params["blocks"], p_cache)
+        carry = (x, aux0, pools, jnp.int32(0))
         if unroll:
-            carry, ys_list = (x, aux0), []
+            ys_list = []
             for pi in range(n_periods):
                 xs_i = jax.tree.map(lambda a: a[pi], xs)
                 carry, y = body(carry, xs_i)
                 ys_list.append(y)
-            (x, aux) = carry
             ys = (jax.tree.map(lambda *ls: jnp.stack(ls), *ys_list)
                   if ys_list and ys_list[0] is not None else None)
         else:
-            (x, aux), ys = jax.lax.scan(body, (x, aux0), xs)
+            carry, ys = jax.lax.scan(body, carry, xs)
+        x, aux, pools, _ = carry
         if ys is not None and mode != "train":
+            for n in pools:
+                ys[n].update(pools[n])
             new_cache["blocks"] = ys
     else:
         aux = aux0

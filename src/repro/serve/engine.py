@@ -238,28 +238,21 @@ class ServingEngine:
         out = []
         for (path, big), small, paged in zip(flat_c, flat_s,
                                              self._paged_flags):
-            ax = _slot_batch_axis(path)
             if not paged:
                 out.append(jax.lax.dynamic_update_slice_in_dim(
-                    big, small.astype(big.dtype), slot, axis=ax))
+                    big, small.astype(big.dtype), slot,
+                    axis=_slot_batch_axis(path)))
                 continue
-            page = big.shape[-3]
+            # big: (n_layers, NB, page, KV * hd), a one-layer stack for a
+            # remainder layer; small: (n_layers, 1, L, KV, hd) or (1, L,
+            # KV, hd), as prefill returns them
+            n_l, _, page, width = big.shape
             span = npages * page
-            if ax == 1:     # stacked: big (n_p, NB, page, KV, hd)
-                seg = small[:, 0]                        # (n_p, L, KV, hd)
-                if span > seg.shape[1]:
-                    seg = jnp.pad(seg, ((0, 0), (0, span - seg.shape[1]),
-                                        (0, 0), (0, 0)))
-                seg = seg[:, :span].reshape(seg.shape[0], npages, page,
-                                            *seg.shape[2:])
-                out.append(big.at[:, pages].set(seg.astype(big.dtype)))
-            else:           # remainder layer: big (NB, page, KV, hd)
-                seg = small[0]
-                if span > seg.shape[0]:
-                    seg = jnp.pad(seg, ((0, span - seg.shape[0]),
-                                        (0, 0), (0, 0)))
-                seg = seg[:span].reshape(npages, page, *seg.shape[1:])
-                out.append(big.at[pages].set(seg.astype(big.dtype)))
+            seg = small.reshape(n_l, -1, width)      # (n_layers, L, KV*hd)
+            if span > seg.shape[1]:
+                seg = jnp.pad(seg, ((0, 0), (0, span - seg.shape[1]), (0, 0)))
+            seg = seg[:, :span].reshape(n_l, npages, page, width)
+            out.append(big.at[:, pages].set(seg.astype(big.dtype)))
         return treedef.unflatten(out)
 
     def _chunk_batch_impl(self, params, cache, pieces, pos, tables, slots):

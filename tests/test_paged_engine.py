@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.configs.base import BlockKind
 from repro.kernels import ops
 from repro.models import model as M
 from repro.serve.engine import Request, ServingEngine
@@ -185,6 +186,23 @@ def test_paged_matches_dense_recurrent(xparams):
     assert got == want
 
 
+def test_paged_matches_dense_mixed_pattern():
+    """A period of global attention and a recurrent block, then a
+    remainder global-attention layer: the period's pool rides the layer
+    scan beside the recurrent state, the remainder's pool is a one-layer
+    stack of its own."""
+    cfg = dataclasses.replace(get_config("recurrentgemma-2b").reduced(),
+                              pattern=(BlockKind.ATTN, BlockKind.RGLRU))
+    flags = M.paged_leaf_flags(cfg, M.init_paged_cache(cfg, 2, 32, 5, 16))
+    assert sum(flags) == 4          # blocks p0 and rem r0, K and V each
+    mparams = M.init_model_params(cfg, jax.random.PRNGKey(2))
+    sched = schedule(11, n=4)
+    kw = dict(max_slots=2, max_len=MAX_LEN)
+    want = run(ServingEngine(cfg, mparams, page_size=0, **kw), sched)
+    got = run(ServingEngine(cfg, mparams, page_size=16, **kw), sched)
+    assert got == want
+
+
 def test_chunked_prefill_state_matches_full_recurrent(xparams):
     assert M.chunked_prefill_supported(XCFG)
     rng = random.Random(3)
@@ -282,8 +300,15 @@ def _paged_rows(rng, kv_lens):
     return bt, sum(need) + 1
 
 
-@pytest.mark.parametrize("layout", HEAD_LAYOUTS, ids=_layout_id)
-def test_paged_decode_kernel_interpret_matches_ref(layout):
+def _pools(rng, layers, npages, layout):
+    """K and V pools as the engine stores them, (layers, npages, PAGE,
+    KV * hd): each layer's pages drawn on their own."""
+    KV, _, hd = layout
+    return [rng.standard_normal((layers, npages, PAGE, KV * hd), np.float32)
+            for _ in range(2)]
+
+
+def _decode_case(layout):
     """Rows: the original two, one key, a length ending on a page
     boundary, a full block table, an inactive row (kv_len 1 on the
     scratch page, as the engine passes it)."""
@@ -291,20 +316,39 @@ def test_paged_decode_kernel_interpret_matches_ref(layout):
     rng = np.random.default_rng(0)
     kv_len = np.array([13, 20, 1, 16, P * PAGE, 1], np.int32)
     bt, npages = _paged_rows(rng, [13, 20, 1, 16, P * PAGE, 0])
-    B, H = len(kv_len), KV * G
-    q = rng.standard_normal((B, 1, H, hd), np.float32)
-    kp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
-    vp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
-    ref_out = ops.paged_decode_attention(q, kp, vp, bt, kv_len, impl="ref")
-    int_out = ops.paged_decode_attention(q, kp, vp, bt, kv_len,
+    q = rng.standard_normal((len(kv_len), 1, KV * G, hd), np.float32)
+    return rng, q, bt, kv_len, npages
+
+
+@pytest.mark.parametrize("layout", HEAD_LAYOUTS, ids=_layout_id)
+def test_paged_decode_kernel_interpret_matches_ref(layout):
+    rng, q, bt, kv_len, npages = _decode_case(layout)
+    kp, vp = _pools(rng, 1, npages, layout)
+    ref_out = ops.paged_decode_attention(q, kp, vp, bt, kv_len, 0,
+                                         impl="ref")
+    int_out = ops.paged_decode_attention(q, kp, vp, bt, kv_len, 0,
                                          impl="interpret")
     np.testing.assert_allclose(np.asarray(int_out), np.asarray(ref_out),
                                rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("C", [5, 1])
-@pytest.mark.parametrize("layout", HEAD_LAYOUTS, ids=_layout_id)
-def test_paged_prefill_kernel_interpret_matches_ref(layout, C):
+@pytest.mark.parametrize("layer", range(3))
+@pytest.mark.parametrize("layout", HEAD_LAYOUTS[:2], ids=_layout_id)
+def test_paged_decode_kernel_reads_its_layer(layout, layer):
+    """A three-layer stack read at ``layer`` matches the oracle given
+    that layer's pages alone."""
+    rng, q, bt, kv_len, npages = _decode_case(layout)
+    kp, vp = _pools(rng, 3, npages, layout)
+    ref_out = ops.paged_decode_attention(q, kp[layer][None],
+                                         vp[layer][None], bt, kv_len, 0,
+                                         impl="ref")
+    int_out = ops.paged_decode_attention(q, kp, vp, bt, kv_len,
+                                         jnp.int32(layer), impl="interpret")
+    np.testing.assert_allclose(np.asarray(int_out), np.asarray(ref_out),
+                               rtol=1e-4, atol=1e-5)
+
+
+def _prefill_case(layout, C):
     """Chunks of C queries ending at the original two lengths, at the
     first key(s), on a page boundary, at a full block table, and an
     inactive row (a chunk at 0 on the scratch page)."""
@@ -312,14 +356,35 @@ def test_paged_prefill_kernel_interpret_matches_ref(layout, C):
     rng = np.random.default_rng(1)
     kv_len = np.array([13, 20, C, 16, P * PAGE, C], np.int32)
     bt, npages = _paged_rows(rng, [13, 20, C, 16, P * PAGE, 0])
-    q_off = kv_len - C
-    B, H = len(kv_len), KV * G
-    q = rng.standard_normal((B, C, H, hd), np.float32)
-    kp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
-    vp = rng.standard_normal((npages, PAGE, KV, hd), np.float32)
-    ref_out = ops.paged_prefill_attention(q, kp, vp, bt, kv_len, q_off,
+    q = rng.standard_normal((len(kv_len), C, KV * G, hd), np.float32)
+    return rng, q, bt, kv_len, kv_len - C, npages
+
+
+@pytest.mark.parametrize("C", [5, 1])
+@pytest.mark.parametrize("layout", HEAD_LAYOUTS, ids=_layout_id)
+def test_paged_prefill_kernel_interpret_matches_ref(layout, C):
+    rng, q, bt, kv_len, q_off, npages = _prefill_case(layout, C)
+    kp, vp = _pools(rng, 1, npages, layout)
+    ref_out = ops.paged_prefill_attention(q, kp, vp, bt, kv_len, q_off, 0,
                                           impl="ref")
+    int_out = ops.paged_prefill_attention(q, kp, vp, bt, kv_len, q_off, 0,
+                                          impl="interpret")
+    np.testing.assert_allclose(np.asarray(int_out), np.asarray(ref_out),
+                               rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", range(3))
+@pytest.mark.parametrize("layout", HEAD_LAYOUTS[:2], ids=_layout_id)
+def test_paged_prefill_kernel_reads_its_layer(layout, layer):
+    """A three-layer stack read at ``layer`` matches the oracle given
+    that layer's pages alone."""
+    rng, q, bt, kv_len, q_off, npages = _prefill_case(layout, 5)
+    kp, vp = _pools(rng, 3, npages, layout)
+    ref_out = ops.paged_prefill_attention(q, kp[layer][None],
+                                          vp[layer][None], bt, kv_len,
+                                          q_off, 0, impl="ref")
     int_out = ops.paged_prefill_attention(q, kp, vp, bt, kv_len, q_off,
+                                          jnp.int32(layer),
                                           impl="interpret")
     np.testing.assert_allclose(np.asarray(int_out), np.asarray(ref_out),
                                rtol=1e-4, atol=1e-5)
